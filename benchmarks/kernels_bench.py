@@ -1,11 +1,11 @@
-"""Kernel/algorithm microbenchmarks (CPU wall time; TPU numbers come from the
-roofline analysis of the dry-run artifacts).
+"""Kernel/algorithm microbenchmarks (wall time on whatever backend runs them;
+rows from a CPU run are not chip numbers).
 
-Measures the beyond-paper algorithmic wins that are observable on CPU:
+Measures the beyond-paper algorithmic wins:
   * continuous O(N) moment curves vs the paper's 5x600-step discrete cascade
   * vectorized policy evaluation throughput (deployments x horizon per sec)
-Plus interpret-mode correctness timing of each Pallas kernel (not a perf
-number on CPU; recorded so regressions in kernel complexity show up).
+Plus the Pallas moment-curve kernel, compiled on a TPU and interpreted
+elsewhere; its row says which (an interpreted time is not a perf number).
 """
 from __future__ import annotations
 
@@ -97,12 +97,15 @@ def run(scale_name: str = "tiny", seed: int = 0) -> list:
                         f"D={d} 5x600steps speedup_vs_continuous="
                         f"{us_disc / us_cont:.1f}x"))
 
-    from repro.kernels.moment_curves.ops import moment_curves_kernel
+    from repro.kernels.moment_curves.ops import (moment_curves_kernel,
+                                                 resolve_interpret)
+    interpret = resolve_interpret()
     kern = jax.jit(lambda b, c: moment_curves_kernel(
-        b, c, grid, AZURE_PRIORS, d_points=32, interpret=True))
+        b, c, grid, AZURE_PRIORS, d_points=32, interpret=interpret))
     us_kern = _timeit(kern, bel, cores, n=2)
-    rows.append(csv_row("kernels/moment_curves_pallas_interpret", us_kern,
-                        "correctness-path; TPU perf in roofline"))
+    mode = "interpret" if interpret else "compiled"
+    rows.append(csv_row(f"kernels/moment_curves_pallas_{mode}", us_kern,
+                        f"D={d} N=48 platform={jax.default_backend()}"))
 
     # fused-aggregate curves: masked sum over alive slots, no [S, N]
     # intermediate, vs the per-slot reference path summed outside

@@ -23,6 +23,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (ablation_marginal, drift_bench, fig1_priors, fig2_pricing,
                fleet_bench, kernels_bench, roofline, scenarios, serve_bench,
                table2_policies, tuning_bench)
@@ -85,6 +87,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows to a BENCH_<scale>.json artifact")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = list(MODULES) if not args.only else args.only.split(",")
     print("name,us_per_call,derived")

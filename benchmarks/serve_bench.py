@@ -16,10 +16,13 @@ i.e. admission without the incrementally-maintained aggregate):
     quarter of the preset's slot table: the naive path's per-decision cost
     scales with cluster state size, the micro-batched path's does not.
   * ``serve/<scale>/sharded`` — the same engine with the slot table sharded
-    over 8 virtual devices (``shards=8``, run in a subprocess under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``); decisions are
-    bit-for-bit the unsharded engine's, so this row measures pure sharding
-    overhead at one-device scale (the win is capacity, not speed, on CPU).
+    over every device this process sees (``shards=N``; on CPU give it
+    virtual devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``). Decisions are
+    bit-for-bit the unsharded engine's, so on virtual devices this row
+    measures pure sharding overhead (the win is capacity, not speed). It
+    runs in this process: a child process could not reach a chip the
+    parent already holds.
   * ``serve/<scale>/deadline_flush`` — the SLO-aware flush scheduler under
     nominal (paced, sub-width) load: recorded p50/p99 submit→decision
     latency from the engine's own histogram, which must meet the configured
@@ -175,49 +178,13 @@ def _measure_telemetry_pair(cfg, grid, pol, *, width: int, n_ticks: int,
     return tuple(float(np.median(lat[i]) * 1e6 / width) for i in (0, 1))
 
 
-def _sharded_entry(scale_name: str, seed: int, width: int, n_ticks: int,
-                   per_tick: int, shards: int) -> dict:
-    """Subprocess body for the sharded row: rebuild the preset's config and
-    run ``_measure`` with the slot table sharded over ``shards`` devices.
-    Must run under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    (the parent drives it via ``_measure_sharded``)."""
-    scale = _scale_for(scale_name)
-    cfg = sim_config(scale)
-    grid = grid_for(scale, cfg)
-    rho = _calibrated_thetas(scale.name).get("second", FALLBACK_RHO)
-    pol = make_policy(SECOND, rho=rho, capacity=cfg.capacity)
-    return _measure(cfg, grid, pol, naive=False, width=width,
-                    n_ticks=n_ticks, per_tick=per_tick, seed=seed,
-                    shards=shards)
-
-
-def _measure_sharded(scale_name: str, *, seed: int, width: int, n_ticks: int,
-                     per_tick: int, shards: int = 8) -> dict:
-    """Run ``_sharded_entry`` in a subprocess with ``shards`` virtual CPU
-    devices (the parent process already initialized jax with one device, so
-    the device count cannot be changed in-process)."""
-    import subprocess
-    import sys
-
-    repo_root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={shards}")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [repo_root, os.path.join(repo_root, "src"),
-         env.get("PYTHONPATH", "")])
-    code = ("import json, sys\n"
-            "from benchmarks.serve_bench import _sharded_entry\n"
-            "a = json.loads(sys.argv[1])\n"
-            "print(json.dumps(_sharded_entry(**a)))\n")
-    args = dict(scale_name=scale_name, seed=seed, width=width,
-                n_ticks=n_ticks, per_tick=per_tick, shards=shards)
-    out = subprocess.run([sys.executable, "-c", code, json.dumps(args)],
-                         env=env, capture_output=True, text=True,
-                         timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"sharded bench subprocess failed:\n{out.stderr}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
+def _measure_sharded(cfg, grid, pol, *, width: int, n_ticks: int,
+                     per_tick: int, seed: int) -> dict:
+    """``_measure`` with the slot table sharded over all visible devices."""
+    shards = jax.device_count()
+    m = _measure(cfg, grid, pol, naive=False, width=width, n_ticks=n_ticks,
+                 per_tick=per_tick, seed=seed, shards=shards)
+    return dict(m, shards=shards)
 
 
 def _measure_deadline(cfg, grid, pol, *, width: int, slo_ms: float,
@@ -328,12 +295,12 @@ def run(scale_name: str = "tiny", seed: int = 0) -> list:
             m["us_per_decision"],
             _derived(m, 1 if naive else width, small.max_slots)))
 
-    # -- device-sharded slot table (8 virtual devices, subprocess) ----------
-    m_sh = _measure_sharded(scale.name, seed=seed, width=width,
-                            n_ticks=n_ticks, per_tick=per_tick, shards=8)
+    # -- device-sharded slot table over every visible device ----------------
+    m_sh = _measure_sharded(cfg, grid, pol, width=width, n_ticks=n_ticks,
+                            per_tick=per_tick, seed=seed)
     rows.append(csv_row(
         f"serve/{scale.name}/sharded", m_sh["us_per_decision"],
-        _derived(m_sh, width, cfg.max_slots) + " shards=8"))
+        f"{_derived(m_sh, width, cfg.max_slots)} shards={m_sh['shards']}"))
 
     # -- deadline-aware flush scheduler at nominal load ---------------------
     slo_ms = 200.0 if smoke else 250.0

@@ -50,6 +50,11 @@ from .processes import PopulationPriors
 
 _EPS = 1e-12
 
+#: contraction precision of every matmul on the moment-curve path: a TPU's
+#: default rounds f32 operands to bf16 (about 3 significant digits); this
+#: keeps them f32 there and changes nothing on CPU
+F32 = jax.lax.Precision.HIGHEST
+
 
 class MomentCurves(NamedTuple):
     """E and V of L over the horizon grid; shapes [..., N]."""
@@ -353,7 +358,8 @@ def _curves_from_packed(p: PackedBelief, t_grid: jax.Array,
     ed_sub = _d_curve_uniform(p.a, p.b, p.eu, p.e_mu_nu, p.cores, w, nd,
                               midpoint=True)
     ones = jnp.ones(ed_sub.shape[:-1] + (1,), ed_sub.dtype)
-    ed = jnp.concatenate([ones, ed_sub], axis=-1) @ w_mat
+    ed = jnp.matmul(jnp.concatenate([ones, ed_sub], axis=-1), w_mat,
+                    precision=F32)
     vd = ed * (1.0 - ed)
 
     er = eq + ebn
@@ -404,8 +410,8 @@ def aggregate_moment_curves(
 
     if s <= block_size:
         cur = _curves_from_packed(packed, t_grid, w_mat, priors, d_points)
-        return MomentCurves(EL=jnp.einsum("...sn,...s->...n", cur.EL, mask),
-                            VL=jnp.einsum("...sn,...s->...n", cur.VL, mask))
+        return MomentCurves(EL=_masked_sum(cur.EL, mask),
+                            VL=_masked_sum(cur.VL, mask))
 
     pad = (-s) % block_size
     if pad:
@@ -429,12 +435,17 @@ def aggregate_moment_curves(
         el_acc, vl_acc = carry
         pk, mk = xs
         cur = _curves_from_packed(pk, t_grid, w_mat, priors, d_points)
-        el_acc = el_acc + jnp.einsum("...sn,...s->...n", cur.EL, mk)
-        vl_acc = vl_acc + jnp.einsum("...sn,...s->...n", cur.VL, mk)
+        el_acc = el_acc + _masked_sum(cur.EL, mk)
+        vl_acc = vl_acc + _masked_sum(cur.VL, mk)
         return (el_acc, vl_acc), None
 
     (el, vl), _ = jax.lax.scan(body, (zero, zero), (blocks, mask_b))
     return MomentCurves(EL=el, VL=vl)
+
+
+def _masked_sum(x: jax.Array, mask: jax.Array) -> jax.Array:
+    """``sum_s x[..., s, n] * mask[..., s]`` as one f32 contraction."""
+    return jnp.einsum("...sn,...s->...n", x, mask, precision=F32)
 
 
 def masked_curve_reduction(curves: MomentCurves, mask: jax.Array,
@@ -456,8 +467,8 @@ def masked_curve_reduction(curves: MomentCurves, mask: jax.Array,
     s = mask.shape[-1]
     if s <= block_size:
         return MomentCurves(
-            EL=jnp.einsum("...sn,...s->...n", curves.EL, mask),
-            VL=jnp.einsum("...sn,...s->...n", curves.VL, mask))
+            EL=_masked_sum(curves.EL, mask),
+            VL=_masked_sum(curves.VL, mask))
 
     pad = (-s) % block_size
     if pad:
@@ -482,8 +493,8 @@ def masked_curve_reduction(curves: MomentCurves, mask: jax.Array,
     def body(carry, xs):
         el_acc, vl_acc = carry
         cur, mk = xs
-        el_acc = el_acc + jnp.einsum("...sn,...s->...n", cur.EL, mk)
-        vl_acc = vl_acc + jnp.einsum("...sn,...s->...n", cur.VL, mk)
+        el_acc = el_acc + _masked_sum(cur.EL, mk)
+        vl_acc = vl_acc + _masked_sum(cur.VL, mk)
         return (el_acc, vl_acc), None
 
     (el, vl), _ = jax.lax.scan(body, (zero, zero), (blocks, mask_b))

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
-
 DEFAULT_BK = 512
 NEG_INF = -1e30
 
@@ -93,7 +91,7 @@ def decode_gqa_grouped(q, k, v, lengths, *, bk=DEFAULT_BK, interpret=False):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

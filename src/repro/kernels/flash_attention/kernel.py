@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
-
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
 NEG_INF = -1e30
@@ -117,7 +115,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

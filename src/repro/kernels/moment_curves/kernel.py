@@ -15,7 +15,8 @@ matmuls that replace cumsum/interp):
 
 All math in f32. cumsum and cumprod (via exp∘cumsum∘log) are expressed as
 matmuls against the static tril matrix so the kernel lowers on TPU without
-relying on scan primitives.
+relying on scan primitives. Mosaic has no ``expm1`` lowering either, so
+``_expm1`` rebuilds it from ``exp`` and a short Taylor polynomial.
 """
 from __future__ import annotations
 
@@ -24,6 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...core.moments import F32
 
 BLOCK_D = 256
 
@@ -31,6 +35,19 @@ BLOCK_D = 256
 (A, B, C0, EU, EU2, EL_, ES1, ESS2, RH1, Z1, RK, Z2, EMUNU, DELTA, ALIVE,
  _PAD) = range(16)
 N_COLS = 16
+
+#: below this |x| the degree-7 Taylor series of expm1 is used: its
+#: truncation error there is under 2e-9 relative, while exp(x) - 1 above it
+#: loses at most ~3e-7 relative to cancellation
+_EXPM1_TAYLOR_MAX = 0.25
+
+
+def _expm1(x):
+    """``exp(x) - 1`` without the cancellation near 0 (``h1``/``kk`` at
+    short horizons sit there); lowers on Mosaic, which has no expm1."""
+    taylor = x * (1.0 + x * (1.0 / 2 + x * (1.0 / 6 + x * (
+        1.0 / 24 + x * (1.0 / 120 + x * (1.0 / 720 + x * (1.0 / 5040)))))))
+    return jnp.where(jnp.abs(x) < _EXPM1_TAYLOR_MAX, taylor, jnp.exp(x) - 1.0)
 
 
 def _curve_block(p, t_ref, tc_ref, tau_ref, tril_ref, w_ref):
@@ -45,11 +62,11 @@ def _curve_block(p, t_ref, tc_ref, tau_ref, tril_ref, w_ref):
     l1 = jnp.log1p(t / b)                            # [D, N]
     l2 = jnp.log1p(2.0 * t / b)
 
-    h1 = rh1 * -jnp.expm1(-z1 * l1)
-    h2 = rh1 * -jnp.expm1(-z1 * l2)
+    h1 = rh1 * -_expm1(-z1 * l1)
+    h2 = rh1 * -_expm1(-z1 * l2)
     eq = eu * h1
     evq = el * (es1 * h1 + 0.5 * ess2 * h2)
-    kk = rk * (-2.0 * jnp.expm1(-z2 * l1) + jnp.expm1(-z2 * l2))
+    kk = rk * (-2.0 * _expm1(-z2 * l1) + _expm1(-z2 * l2))
     veq = jnp.maximum(eu2 * kk - eq * eq, 0.0)
     vq = evq + veq
 
@@ -68,21 +85,21 @@ def _curve_block(p, t_ref, tc_ref, tau_ref, tril_ref, w_ref):
     p_lag = jnp.exp(-a * jnp.log1p(tau / b))
     s = (q * w_step) * jnp.log1p(-jnp.minimum(p_lag, 1.0 - 1e-7))
     cums = jax.lax.dot_general(
-        s, tril_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # inclusive cumsum
+        s, tril_ref[...], (((1,), (0,)), ((), ())),     # inclusive cumsum
+        precision=F32, preferred_element_type=jnp.float32)
     p_self = jnp.exp(-a * jnp.log1p(tc / b))
     log_dead = c * jnp.log1p(-jnp.minimum(p_self, 1.0 - 1e-7)) + cums
-    factor = jnp.maximum(-jnp.expm1(log_dead), 1e-37)
+    factor = jnp.maximum(-_expm1(log_dead), 1e-37)
     logf = jnp.log(factor)
     log_ed = jax.lax.dot_general(
         logf, tril_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=F32, preferred_element_type=jnp.float32)
     ed_sub = jnp.exp(log_ed)                         # cumprod [D, ND]
     ones = jnp.ones_like(ed_sub[:, :1])
     ed_ext = jnp.concatenate([ones, ed_sub], axis=1)  # anchor (t=0, 1)
     ed = jax.lax.dot_general(
-        ed_ext, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # [D, N]
+        ed_ext, w_ref[...], (((1,), (0,)), ((), ())),   # [D, N]
+        precision=F32, preferred_element_type=jnp.float32)
     vd = ed * (1.0 - ed)
 
     er = eq + eb
@@ -187,5 +204,9 @@ def moment_curves_agg_packed(params: jax.Array, t_grid: jax.Array,
             jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
+        # every grid step accumulates into the same [1, N] output block, so
+        # the steps must run in order on one core
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(params, t_grid, tc, tau, tril, w_interp)

@@ -16,8 +16,11 @@ Two entry points:
   never materialize [D, N] outside VMEM). Drop-in replacement for
   ``core.moments.aggregate_moment_curves``.
 
-Both run in interpret mode on CPU — a first-class, tested fallback path, not
-just a debugging aid (the tier-1 suite exercises it on every run).
+Both compile through Mosaic when JAX's default backend is a TPU and run in
+interpret mode everywhere else (``resolve_interpret``) — on CPU a
+first-class, tested fallback path, not just a debugging aid (the tier-1
+suite exercises it on every run). ``chip_smoke.py`` asserts that the chip
+takes the compiled path.
 """
 from __future__ import annotations
 
@@ -56,6 +59,14 @@ def _pack(bel: GammaBelief, cores, priors: PopulationPriors,
     return packed, d
 
 
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The kernels' ``interpret`` flag: an explicit value wins; ``None``
+    means compiled on a TPU backend, interpreted on any other."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 def _grids(t_grid: jax.Array, d_points: int):
     tc, tau, w_mat = interp_matrix(t_grid.astype(jnp.float32), d_points)
     return tc[None, :], tau[None, :], w_mat
@@ -66,8 +77,7 @@ def moment_curves_kernel(bel: GammaBelief, cores: jax.Array,
                          *, d_points: int = 32,
                          interpret: bool | None = None) -> MomentCurves:
     """Kernel-backed moment curves. bel fields/cores: [D]; t_grid: [N]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     params, d = _pack(bel, cores, priors)
     tc, tau, w_mat = _grids(t_grid, d_points)
     el, vl = moment_curves_packed(
@@ -81,8 +91,7 @@ def aggregate_moment_curves_kernel(
         t_grid: jax.Array, priors: PopulationPriors, *, d_points: int = 32,
         interpret: bool | None = None) -> MomentCurves:
     """Aggregate (sum over alive slots) curves [N] via the fused kernel."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     params, _ = _pack(bel, cores, priors, alive=alive)
     tc, tau, w_mat = _grids(t_grid, d_points)
     el, vl = moment_curves_agg_packed(

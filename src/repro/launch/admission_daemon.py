@@ -125,7 +125,11 @@ def serve_loop(engine, stream, keys, *, log_every: int = 0,
     With a flush SLO configured on the engine, the deadline scheduler owns
     flushing: the loop only submits and awaits futures (resolved by the
     scheduler thread within the SLO); otherwise it drives the legacy
-    caller-flushed protocol, one full flush per tick."""
+    caller-flushed protocol, one full flush per tick.
+
+    The summary's ``accept`` is the ``[T, A]`` verdict of every served
+    arrival (False where no arrival was due), laid out as ``make_run``'s
+    ``record_decisions`` output so the two can be compared lane for lane."""
     from ..serve import Arrival
 
     slo_mode = getattr(engine, "flush_slo_s", None) is not None
@@ -134,7 +138,7 @@ def serve_loop(engine, stream, keys, *, log_every: int = 0,
     n_steps = keys.shape[0]
     max_a = int(np.asarray(stream.c0.shape[1]))
     n_arr = np.asarray(stream.n_arrivals)
-    admitted = 0
+    accept = np.zeros((n_steps, max_a), bool)
     t0 = time.time()
     ticks = 0
     for t in range(n_steps):
@@ -147,19 +151,20 @@ def serve_loop(engine, stream, keys, *, log_every: int = 0,
                 for a in range(min(int(n_arr[t]), max_a))]
         if not slo_mode:
             engine.flush()
-        admitted += sum(f.result() for f in futs)
+        accept[t, :len(futs)] = [f.result() for f in futs]
         if log_every and (t + 1) % log_every == 0:
             m = engine.metrics()
             log.info("t=%d/%d util=%.3f admitted=%d/%d", t + 1, n_steps,
-                     float(m.utilization), admitted, engine.decisions)
+                     float(m.utilization), int(accept.sum()),
+                     engine.decisions)
         if throttle_s > 0.0:
             time.sleep(throttle_s)
     if slo_mode:
         engine.stop()      # joins the scheduler; final drain inside
     else:
         engine.flush()     # resolve anything a racing submitter queued
-    return {"admitted": admitted, "decisions": engine.decisions,
-            "ticks": ticks, "seconds": time.time() - t0}
+    return {"admitted": int(accept.sum()), "decisions": engine.decisions,
+            "ticks": ticks, "seconds": time.time() - t0, "accept": accept}
 
 
 def snapshot_log_line(snap: dict) -> str:
@@ -182,7 +187,8 @@ def snapshot_log_line(snap: dict) -> str:
     return json.dumps(out, sort_keys=True)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The daemon's command line (``build_engine`` takes its namespace)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--capacity", type=float, default=4096.0)
     ap.add_argument("--hours", type=float, default=2000.0)
@@ -218,7 +224,14 @@ def main():
     ap.add_argument("--flush-slo-ms", type=float, default=None, metavar="MS",
                     help="decision-latency SLO: run the deadline-aware "
                          "flush scheduler instead of per-tick flushing")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    from ..compile_cache import enable_compile_cache
+
+    args = build_parser().parse_args()
+    enable_compile_cache()
     set_level("INFO")  # the daemon is a CLI: its operational log is output
 
     engine, stream, keys, param = build_engine(args)

@@ -232,9 +232,7 @@ def _cache_update(cache_arr, new, slot, mesh):
         upd = jnp.where(inb, n.astype(c.dtype), old)
         return jax.lax.dynamic_update_slice(c, upd, (z, ls_c, z, z))
 
-    from ..compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(PartitionSpec(dp_spec, "model", None, None),
                   PartitionSpec(dp_spec, None, None, None),

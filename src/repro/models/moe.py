@@ -172,9 +172,7 @@ def moe_local(params: dict, cfg: MoEConfig, x: jax.Array, mesh) -> MoEOut:
         ) / (t * k)
         return y.reshape(b, s, d), aux, dropped
 
-    from ..compat import shard_map
-
-    y, aux, dropped = shard_map(
+    y, aux, dropped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(PartitionSpec(), PartitionSpec("model", None, None),
                   PartitionSpec("model", None, None),

@@ -41,7 +41,7 @@ import numpy as np
 from ..core.belief import (GammaBelief, apply_pseudo_observations,
                            belief_from_prior, observe_initial_size,
                            update_on_events)
-from ..core.moments import (MomentCurves, aggregate_moment_curves,
+from ..core.moments import (F32, MomentCurves, aggregate_moment_curves,
                             masked_curve_reduction, moment_curves,
                             moment_curves_fused)
 from ..core.policies import (ZEROTH, PolicyParams, admit_sequential,
@@ -358,10 +358,10 @@ def _place_arrivals(state: SimState, accept, stream_t: ArrivalStream, cfg: SimCo
 
     hit = free[None, :] & (rank[None, :] == ordinal[:, None]) & accept[:, None]
     placed = jnp.any(hit, axis=0)                      # [S]
+    src = jnp.argmax(hit, axis=0)                      # its arrival, if placed
 
     def merge(old, new_a):
-        upd = hit.astype(old.dtype).T @ new_a
-        return jnp.where(placed, upd, old)
+        return jnp.where(placed, new_a[src], old)
 
     cores = merge(state.cores, stream_t.c0)
     params = jax.tree.map(lambda o, n: merge(o, n), state.params,
@@ -617,8 +617,10 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
         slots, placed_arrival = _place_arrivals(cs.slots, res.accept,
                                                 stream_t, cfg)
         placed_f = placed_arrival.astype(jnp.float32)
-        agg_el = cs.agg_el + jnp.einsum("an,a->n", cand.EL, placed_f)
-        agg_vl = cs.agg_vl + jnp.einsum("an,a->n", cand.VL, placed_f)
+        agg_el = cs.agg_el + jnp.einsum("an,a->n", cand.EL, placed_f,
+                                        precision=F32)
+        agg_vl = cs.agg_vl + jnp.einsum("an,a->n", cand.VL, placed_f,
+                                        precision=F32)
         tel = cs.tel
         if cfg.telemetry:
             tel = fold_decisions(tel, res.accept, valid, diag.fits,
@@ -693,8 +695,6 @@ def _shard_over_slots(core: AdmissionCore, mesh) -> AdmissionCore:
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..compat import shard_map
-
     cfg, grid = core.cfg, core.grid
     if len(mesh.axis_names) != 1:
         raise ValueError(f"mesh must have exactly one axis, got "
@@ -756,15 +756,16 @@ def _shard_over_slots(core: AdmissionCore, mesh) -> AdmissionCore:
         agg = masked_curve_reduction(jax.tree.map(gather, cur), gather(mask))
         return cs._replace(agg_el=agg.EL, agg_vl=agg.VL, tel=tel)
 
-    sm_refresh = shard_map(_local_refresh, mesh=mesh, in_specs=(cs_specs,),
-                           out_specs=cs_specs, check_vma=False)
+    sm_refresh = jax.shard_map(_local_refresh, mesh=mesh,
+                               in_specs=(cs_specs,), out_specs=cs_specs,
+                               check_vma=False)
 
     def _local_apply(key, cs: CoreState, capacity):
         full, out = core.apply_events(
             key, cs._replace(slots=gather_slots(cs.slots)), capacity)
         return full._replace(slots=slice_slots(full.slots)), out
 
-    sm_apply = shard_map(
+    sm_apply = jax.shard_map(
         _local_apply, mesh=mesh, in_specs=(P(), cs_specs, P()),
         out_specs=(cs_specs, P()), check_vma=False)
 
@@ -779,7 +780,7 @@ def _shard_over_slots(core: AdmissionCore, mesh) -> AdmissionCore:
             stream_t, valid)
         return full._replace(slots=slice_slots(full.slots)), accept
 
-    sm_decide = shard_map(
+    sm_decide = jax.shard_map(
         _local_decide, mesh=mesh,
         in_specs=(P(), cs_specs, P(), P(), P(), P()),
         out_specs=(cs_specs, P()), check_vma=False)
@@ -790,7 +791,7 @@ def _shard_over_slots(core: AdmissionCore, mesh) -> AdmissionCore:
             stream_t, valid)
         return full._replace(slots=slice_slots(full.slots)), accept, diag
 
-    sm_decide_traced = shard_map(
+    sm_decide_traced = jax.shard_map(
         _local_decide_traced, mesh=mesh,
         in_specs=(P(), cs_specs, P(), P(), P(), P()),
         out_specs=(cs_specs, P(), P()), check_vma=False)

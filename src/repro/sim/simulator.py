@@ -471,12 +471,10 @@ def shard_batch_over_devices(batched, devices, axis: str,
     """
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..compat import shard_map
-
     mesh = Mesh(np.asarray(devices), (axis,))
     in_specs = (P(axis),) * n_batch_args + (P(),) * n_replicated_args
-    return jax.jit(shard_map(batched, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(axis), check_vma=False))
+    return jax.jit(jax.shard_map(batched, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(axis), check_vma=False))
 
 
 # bounded LRU: a weak-keyed cache cannot work here (the cached shard_map

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def make_pipeline_forward(stage_fn: Callable, n_stages: int, mesh,
                           data_axis: str | None = "data"):
@@ -71,7 +69,7 @@ def make_pipeline_forward(stage_fn: Callable, n_stages: int, mesh,
 
     def pipeline(params, x_mb):
         param_specs = jax.tree.map(lambda _: P("stage"), params)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(param_specs, x_spec),
             out_specs=x_spec,

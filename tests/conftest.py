@@ -1,6 +1,7 @@
 """Shared test configuration.
 
-Enables jax's persistent compilation cache (repo-local, gitignored): the
+Enables jax's persistent compilation cache (``repro.compile_cache``:
+``$JAX_COMPILATION_CACHE_DIR`` or the gitignored ``<repo>/.jax_cache``): the
 suite is compile-dominated on CPU, so warm reruns — the common local dev
 loop — skip most XLA work. Cold CI runs are unaffected.
 
@@ -21,8 +22,9 @@ import time
 import jax
 import pytest
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 _BUDGET_FILE = os.path.join(os.path.dirname(__file__), "time_budget.json")

@@ -1,8 +1,8 @@
 """AdmissionCore extraction is pure code motion.
 
 ``tests/data/golden_sim_metrics.npz`` (generated by
-``tools/gen_sim_goldens.py`` against the pre-refactor monolithic simulator)
-pins every metric field of three reference runs; the refactored
+``tools/gen_sim_goldens.py``, which says how it depends on JAX's random
+streams) pins every metric field of three reference runs; the refactored
 ``make_run``/``make_fleet_run`` — now thin scan drivers over
 ``sim.core.make_admission_core`` — must reproduce them **bit-for-bit**, not
 approximately.

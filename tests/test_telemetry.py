@@ -472,8 +472,7 @@ def test_logger_rooted_and_level_controls(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_daemon_sigterm_graceful_with_live_metrics():
-    env = dict(os.environ, PYTHONPATH="src",
-               JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"))
+    env = dict(os.environ, PYTHONPATH="src")  # main() enables the cache
     cmd = [sys.executable, "-m", "repro.launch.admission_daemon",
            "--capacity", "500", "--hours", "720", "--dt", "24",
            "--max-slots", "96", "--micro-batch", "4",

@@ -1,10 +1,13 @@
 """Regenerate tests/data/golden_sim_metrics.npz — the bit-for-bit anchor for
 the AdmissionCore extraction.
 
-The goldens were captured from the pre-extraction simulator (PR 5 state) on
-the reference CPU box; the core-extraction tests assert today's
-``make_run``/``make_fleet_run`` reproduce them exactly. Regenerate ONLY when
-a deliberate semantic change to the simulator lands (and say so in the PR):
+The tests assert that ``make_run``/``make_fleet_run`` reproduce these
+metrics exactly. They depend on JAX's random streams: the file is generated
+under the ``jax_threefry_partitionable=True`` default of the pinned JAX
+(metrics drawn under the older non-partitionable streams differ, and are
+reproduced only with ``JAX_THREEFRY_PARTITIONABLE=0``). Regenerate ONLY when
+a deliberate semantic change to the simulator or its random streams lands
+(and say so in the change):
 
   PYTHONPATH=src python tools/gen_sim_goldens.py
 """
