@@ -22,6 +22,27 @@ ephemeral port and logs it). SIGTERM/SIGINT shut down gracefully: the serve
 loop stops at the next tick boundary, pending futures are flushed, and the
 final metrics snapshot is logged before exit 0.
 
+Where a decision's time goes, on the same endpoint (all ``repro_admission_``):
+
+  * ``queue_wait_seconds`` (histogram): submit to the flush that takes the
+    request. High while the rest are low: the flush thread is late, held
+    off by ticks (see the lock waits) or by other host threads.
+  * ``answer_seconds`` (histogram): that flush's start to the request's
+    answer, including the parts decided before its own. The two add up to
+    ``decision_latency_seconds``.
+  * ``decide_wait_seconds`` (histogram): one decide's device work as the
+    host waits for it; ``part_host_seconds_total``: the rest of each flush
+    part on the host (stacking requests, dispatch, answering).
+  * ``tick_host_seconds`` (histogram): how long each tick holds the engine
+    state lock, during which no flush can run.
+  * ``lock_wait_seconds_total{taker="flush"|"tick"}`` and
+    ``lock_acquires_total{taker}``: time spent waiting for that lock, and
+    how often it was taken; flushes waiting on ticks show here.
+  * ``pump_busy_seconds_total``: seconds the flush thread spent flushing;
+    its rate is the thread's busy share, near 1 when it is saturated.
+  * ``compiled_programs{step}`` (gauge): programs compiled by each jitted
+    step; a step that grows while serving is recompiling on the hot path.
+
 Scaling: ``--shards N`` shards the slot table over N devices (one engine,
 bit-for-bit the single-device decisions — see ``sim.core.slot_mesh``);
 ``--flush-slo-ms L`` switches from per-tick caller-driven flushing to the
@@ -50,7 +71,7 @@ from ..core import AZURE_PRIORS, FIRST, SECOND, ZEROTH, geometric_grid, \
     make_policy
 from ..core.policies import fleet_policy
 from ..models.registry import ARCH_NAMES
-from ..obs import get_logger, set_level
+from ..obs import HostHistogram, get_logger, set_level
 
 log = get_logger("launch.admission_daemon")  # stable name under python -m
 
@@ -168,8 +189,9 @@ def serve_loop(engine, stream, keys, *, log_every: int = 0,
 
 
 def snapshot_log_line(snap: dict) -> str:
-    """One JSON line of the scalar snapshot fields (histograms reduced to
-    p50/p99 and counts) — what the daemon logs at shutdown."""
+    """One JSON line of the scalar snapshot fields (the latency histogram
+    reduced to p50/p99, the others to means) — what the daemon logs at
+    shutdown."""
     eng = dict(snap.get("engine", {}))
     lat = eng.pop("decision_latency_seconds", None)
     batch = eng.pop("flush_batch_size", None)
@@ -178,6 +200,11 @@ def snapshot_log_line(snap: dict) -> str:
         eng["latency_p99_s"] = round(lat.percentile(0.99), 6)
     if batch is not None:
         eng["mean_batch"] = round(batch.sum / max(batch.total, 1), 3)
+    for name, hist in list(eng.items()):
+        if isinstance(hist, HostHistogram):
+            eng[name.replace("_seconds", "_mean_s")] = round(
+                hist.sum / max(hist.total, 1), 6)
+            del eng[name]
     out = {"engine": eng}
     tel = snap.get("telemetry")
     if tel:

@@ -3,7 +3,7 @@
 Three dependency-free pieces (stdlib only — no ``prometheus_client``):
 
   * ``HostHistogram`` — a fixed-bucket streaming histogram for host-side
-    latencies/sizes (decision latency, flush batch size): O(1) observe,
+    latencies/sizes (decision latency, flush batch size): O(log n) observe,
     cumulative bucket counts, and p50/p99 estimates by linear interpolation
     within the landing bucket.
   * ``render_prometheus(metrics)`` — render a list of ``Metric`` families to
@@ -20,6 +20,7 @@ the admission daemon serves it under ``--metrics-port``.
 """
 from __future__ import annotations
 
+import bisect
 import math
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -54,13 +55,10 @@ class HostHistogram:
 
     def observe(self, value: float) -> None:
         value = float(value)
-        # linear scan: bucket counts are small and observe is not the hot
-        # path's inner loop (one call per flush / per decision batch)
-        idx = len(self.buckets)
-        for i, edge in enumerate(self.buckets):
-            if value <= edge:
-                idx = i
-                break
+        # the first edge >= value (an edge's bucket holds value <= edge);
+        # past the last edge, and NaN, the +Inf bucket
+        idx = (bisect.bisect_left(self.buckets, value) if value == value
+               else len(self.buckets))
         self.counts[idx] += 1
         self.total += 1
         self.sum += value
@@ -152,8 +150,10 @@ def snapshot_to_prometheus(snap: dict) -> str:
 
     Device-side telemetry counters become ``repro_admission_*`` counters and
     the occupancy/staleness histograms become gauges per bin; the host-side
-    engine histograms (decision latency, flush batch size) are exposed as
-    native Prometheus histograms plus queue-depth / pump-idle gauges.
+    engine histograms (decision latency and its phases, tick lock hold,
+    flush batch size) are exposed as native Prometheus histograms, the
+    host phase sums as ``*_seconds_total`` counters, plus queue-depth and
+    compiled-program gauges.
     """
     mets: list[Metric] = []
 
@@ -175,18 +175,43 @@ def snapshot_to_prometheus(snap: dict) -> str:
     counter("deadline_misses_total",
             "Decisions whose submit->decision latency exceeded the flush SLO",
             eng.get("deadline_misses", 0))
+    counter("pump_busy_seconds_total",
+            "Seconds the background flush thread spent inside flush()",
+            eng.get("pump_busy_seconds", 0.0))
+    counter("part_host_seconds_total",
+            "Host seconds of flush parts outside their decide wait",
+            eng.get("part_host_seconds", 0.0))
+    waits = eng.get("lock_wait_seconds", {})
+    if waits:
+        mets.append(Metric(
+            "repro_admission_lock_wait_seconds_total", "counter",
+            "Seconds waited for the engine state lock, by taker",
+            [({"taker": t}, w["sum"]) for t, w in sorted(waits.items())]))
+        mets.append(Metric(
+            "repro_admission_lock_acquires_total", "counter",
+            "Outermost acquires of the engine state lock, by taker",
+            [({"taker": t}, w["count"]) for t, w in sorted(waits.items())]))
     gauge("queue_depth", "Pending requests in the micro-batch queue",
           [({}, eng.get("queue_depth", 0))])
-    gauge("pump_idle_fraction", "Fraction of pump loop time spent idle",
-          [({}, eng.get("pump_idle_fraction", 0.0))])
     gauge("shard_count", "Devices the slot table is sharded over",
           [({}, eng.get("n_shards", 1))])
     gauge("flush_slo_seconds",
           "Configured decision-latency SLO (0 = caller-driven flushing)",
           [({}, eng.get("flush_slo_ms", 0.0) / 1e3)])
-    for hname, help_ in (("decision_latency_seconds",
-                          "submit->decision latency"),
-                         ("flush_batch_size", "Decisions per flush")):
+    compiled = eng.get("compiled_programs")
+    if compiled:
+        gauge("compiled_programs", "Programs compiled by each jitted step",
+              [({"step": k}, v) for k, v in sorted(compiled.items())])
+    for hname, help_ in (
+            ("decision_latency_seconds", "submit->decision latency"),
+            ("queue_wait_seconds",
+             "submit -> drain of the flush that takes the request"),
+            ("answer_seconds",
+             "flush drain -> the request's future resolved"),
+            ("decide_wait_seconds",
+             "decide jit call returned -> accept mask on the host"),
+            ("tick_host_seconds", "Seconds a tick holds the state lock"),
+            ("flush_batch_size", "Decisions per flush")):
         hist = eng.get(hname)
         if isinstance(hist, HostHistogram):
             mets.append(Metric(f"repro_admission_{hname}", "histogram",

@@ -33,6 +33,7 @@ cluster before per-cluster admission, mirroring ``make_fleet_run`` exactly.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -157,9 +158,16 @@ class OnlineAdmissionEngine:
 
     Observability: with ``cfg.telemetry`` the ``CoreState`` carries the
     device telemetry rider through every step, and ``metrics_snapshot()``
-    exports it (plus host-side decision-latency / flush-batch-size
-    histograms and queue/pump gauges) without synchronizing the pump —
-    that is what the daemon's ``/metrics`` endpoint serves. An attached
+    exports it (plus the host-side phase counters below and queue/pump
+    gauges) without synchronizing the pump — that is what the daemon's
+    ``/metrics`` endpoint serves. The host counters are always on and read
+    ``time.monotonic``: a request's submit→answer time splits into its
+    queue wait (submit to the drain of the flush that takes it) and its
+    answer time (drain to its future resolved); each flush part into its
+    decide wait (jit call returned to accept mask on the host) and the
+    rest; ``tick`` and ``flush`` record how long they waited for the state
+    lock and ``tick`` how long it held it. The same phases are
+    ``repro.engine.*`` profiler spans (``obs.tracing.annotate``). An attached
     ``obs.tracing.DecisionTracer`` additionally receives one structured
     record per ``submit``-path decision (single-cluster engines include
     the policy score via the traced decide path), and an attached
@@ -248,15 +256,22 @@ class OnlineAdmissionEngine:
             # when no observation crossed the SLO edge) instead of smearing
             # sub-SLO latencies into a coarse decade-wide default bucket
             slo = self.flush_slo_s
-            self._hist_latency = HostHistogram(
+            hist = lambda: HostHistogram(
                 log_buckets(slo / 512.0, slo, 10) + (2.0 * slo, 4.0 * slo))
         else:
-            self._hist_latency = HostHistogram()  # submit->decision, seconds
+            hist = HostHistogram
+        self._hist_latency = hist()               # submit->decision, seconds
+        self._hist_queue_wait = hist()            # submit->drain
+        self._hist_answer = hist()                # drain->future resolved
+        self._hist_decide_wait = hist()           # jit returned->accept mask
+        self._hist_tick_host = hist()             # tick's lock held
         self._hist_batch = HostHistogram(
             log_buckets(1.0, float(max(self.width, 2)), 8))
+        self._part_host_s = 0.0                   # flush parts minus waits
+        self._decide_wait_s = 0.0                 # the last decide's wait
+        self._lock_wait = {"flush": [0.0, 0], "tick": [0.0, 0]}
         self.n_flushes = 0
         self.n_refreshes = 0
-        self._pump_idle_s = 0.0
         self._pump_busy_s = 0.0
         self._req_id = 0
         self._last_diag = None                    # DecisionDiag of last slice
@@ -434,15 +449,18 @@ class OnlineAdmissionEngine:
         """
         if (key is None) == (events is None):
             raise ValueError("tick() needs exactly one of key= or events=")
-        with self._state_lock:
-            self._close_window()
+        with self._locked("tick"), annotate("repro.engine.tick"):
+            t0 = time.monotonic()
+            with annotate("repro.engine.tick.close"):
+                self._close_window()
             if self.ticks % self.k_refresh == 0 and not self.naive:
                 with annotate("repro.engine.refresh"):
                     self._cs = self._j_refresh(self._cs)
                 self.n_refreshes += 1
-            with annotate("repro.engine.tick"):
-                if events is not None:
+            if events is not None:
+                with annotate("repro.engine.tick.events"):
                     ev = jax.tree.map(jnp.asarray, events)
+                with annotate("repro.engine.tick.ingest"):
                     self._cs, self._out = self._j_ingest(self._caps,
                                                          self._cs, ev)
                     # derive from the engine's seed chain: PRNGKey(self.ticks)
@@ -451,12 +469,31 @@ class OnlineAdmissionEngine:
                     # belief noise
                     self._step_key = jax.random.fold_in(self._base_key,
                                                         self.ticks)
-                else:
+            else:
+                with annotate("repro.engine.tick.ingest"):
                     self._cs, self._out = self._j_tick(key, self._cs)
-                    self._step_key = key
+                self._step_key = key
             self._util = self._out.util
             self._acc = self._rej = 0.0
             self.ticks += 1
+            self._hist_tick_host.observe(time.monotonic() - t0)
+
+    @contextlib.contextmanager
+    def _locked(self, taker: str):
+        """Hold ``_state_lock`` as ``taker``'s outermost acquire, counting
+        the wait for it under ``lock_wait_seconds{taker}``."""
+        t0 = time.monotonic()
+        waiting = annotate("repro.engine.lock_wait")
+        waiting.__enter__()
+        # acquired by a with statement, not an acquire() call: the
+        # profiler's Python tracer records such a call as a span of its own,
+        # which would cover the wait in place of the lock_wait span
+        with self._state_lock:
+            waiting.__exit__(None, None, None)
+            stat = self._lock_wait[taker]
+            stat[0] += time.monotonic() - t0
+            stat[1] += 1
+            yield
 
     def _close_window(self):
         with self._state_lock:
@@ -500,30 +537,42 @@ class OnlineAdmissionEngine:
         ``tick()``/``metrics()`` cannot close the window mid-flight. A chunk
         that raises fails every remaining future with the exception instead
         of leaving callers blocked forever."""
-        with self._state_lock:
+        with self._locked("flush"):
             if self._out is None:
                 raise RuntimeError("flush() before the first tick()")
             with self._lock:
                 pending, self._pending = self._pending, []
+            t0 = time.monotonic()
             if not pending:
                 return 0
             chunk = 1 if self.naive else self.width
-            t0 = time.monotonic()
             done = 0
+            answered = []        # each answer's time, observed after the drain
             try:
                 with annotate("repro.engine.flush"):
                     for i in range(0, len(pending), chunk):
                         part = pending[i:i + chunk]
-                        accept = self._decide([a for a, _, _ in part])
-                        self._trace_part(part, accept)
-                        for (_, fut, _), ok in zip(part, accept):
-                            fut.set_result(bool(ok))
+                        with annotate("repro.engine.flush.part"):
+                            t_part = time.monotonic()
+                            accept = self._decide([a for a, _, _ in part])
+                            with annotate("repro.engine.flush.resolve"):
+                                self._trace_part(part, accept)
+                                for (_, fut, _), ok in zip(part, accept):
+                                    fut.set_result(bool(ok))
+                                    answered.append(time.monotonic())
+                            self._part_host_s += (time.monotonic() - t_part
+                                                  - self._decide_wait_s)
                         done = i + len(part)
             except BaseException as exc:
                 for _, fut, _ in pending[done:]:
                     if not fut.done():
                         fut.set_exception(exc)
                 raise
+            # the phases are observed once every future is resolved, so no
+            # request waits on the histograms of those before it
+            for (_, _, t_sub), t_ans in zip(pending, answered):
+                self._hist_queue_wait.observe(t0 - t_sub)
+                self._hist_answer.observe(t_ans - t0)
             cost = time.monotonic() - t0
             self._flush_cost_s = (cost if self._flush_cost_s == 0.0
                                   else 0.8 * self._flush_cost_s + 0.2 * cost)
@@ -585,7 +634,10 @@ class OnlineAdmissionEngine:
                 else:
                     self._cs, accept, self._util = fn(
                         self.policy, self._cs, self._util, stream_t, valid)
-                accept = np.asarray(accept)
+                t_ret = time.monotonic()
+                with annotate("repro.engine.flush.wait"):
+                    accept = np.asarray(accept)
+                self._observe_decide_wait(time.monotonic() - t_ret)
                 n_acc = float(np.sum(accept))
                 self._acc += n_acc
                 self._rej += float(np.sum(np.asarray(valid))) - n_acc
@@ -595,20 +647,29 @@ class OnlineAdmissionEngine:
                  self._rej_all) = fn(
                     self.policy, self._cs, self._util, stream_t, valid, rkey,
                     jnp.asarray(self._rej_all, jnp.float32))
-                self._acc = self._acc + np.asarray(n_acc)
+                t_ret = time.monotonic()
+                with annotate("repro.engine.flush.wait"):
+                    n_acc = np.asarray(n_acc)
+                self._observe_decide_wait(time.monotonic() - t_ret)
+                self._acc = self._acc + n_acc
                 self._rej = self._rej + np.asarray(n_rej)
                 accept = np.asarray(jnp.any(accept_c, axis=0))
             self.decisions += int(np.sum(np.asarray(valid)))
         return accept
 
+    def _observe_decide_wait(self, wait: float) -> None:
+        self._decide_wait_s = wait
+        self._hist_decide_wait.observe(wait)
+
     def _decide(self, arrivals: list) -> np.ndarray:
         """Stack ``Arrival`` tickets into one padded fixed-width slice."""
         n = len(arrivals)
         width = 1 if self.naive else self.width
-        lanes = [self._lane(a) for a in arrivals]
-        lanes += [self._pad] * (width - n)
-        batch = jax.tree.map(lambda *xs: np.stack(xs), *lanes)
-        valid = np.arange(width) < n
+        with annotate("repro.engine.flush.stack"):
+            lanes = [self._lane(a) for a in arrivals]
+            lanes += [self._pad] * (width - n)
+            batch = jax.tree.map(lambda *xs: np.stack(xs), *lanes)
+            valid = np.arange(width) < n
         return self.decide_slice(batch, valid)[:n]
 
     def _lane(self, a: Arrival) -> ArrivalStream:
@@ -645,13 +706,12 @@ class OnlineAdmissionEngine:
 
     def _pump_loop(self, interval_s: float):
         while not self._stop.is_set():
-            t0 = time.monotonic()
             if self.n_pending:
+                t0 = time.monotonic()
                 self.flush()
                 self._pump_busy_s += time.monotonic() - t0
             else:
                 self._stop.wait(interval_s)
-                self._pump_idle_s += time.monotonic() - t0
 
     def _deadline_loop(self):
         """Latency-SLO-aware flush scheduler. Each ``submit()`` stamps its
@@ -682,9 +742,7 @@ class OnlineAdmissionEngine:
                         else:
                             self._work.wait(wait)
                     else:
-                        t0 = time.monotonic()
                         self._work.wait()
-                        self._pump_idle_s += time.monotonic() - t0
             if fire:
                 t0 = time.monotonic()
                 self.flush()
@@ -701,6 +759,22 @@ class OnlineAdmissionEngine:
         self.flush()
 
     # -------------------------------------------------------------- metrics
+
+    #: the jitted steps whose compiled programs ``metrics_snapshot`` counts
+    JIT_STEPS = ("close", "refresh", "tick", "ingest", "decide",
+                 "decide_traced", "naive")
+
+    def _compiled_programs(self) -> dict:
+        """Executables cached by each jitted step (``_j_<step>``); a step
+        that does not exist here, or a JAX without the cache count, is left
+        out."""
+        out = {}
+        for step in self.JIT_STEPS:
+            size = getattr(getattr(self, "_j_" + step, None), "_cache_size",
+                           None)
+            if callable(size):
+                out[step] = int(size())
+        return out
 
     def metrics(self):
         """Run-so-far metrics, assembled exactly as the offline drivers
@@ -728,9 +802,13 @@ class OnlineAdmissionEngine:
             horizon_hours=horizon))
 
     def metrics_snapshot(self) -> dict:
-        """Non-blocking observability snapshot: engine counters, the
-        decision-latency / flush-batch-size host histograms, and (with
-        ``cfg.telemetry``) the device telemetry rider's summary.
+        """Non-blocking observability snapshot: engine counters, the host
+        phase histograms and sums (decision latency, queue wait, answer
+        time, decide wait, tick lock hold, flush batch size, lock waits,
+        the pump's busy seconds), the programs each jitted step has
+        compiled, and (with ``cfg.telemetry``) the device telemetry rider's
+        summary. ``time_s`` is the snapshot's ``time.monotonic``, so two
+        snapshots give rates over the interval between them.
 
         With a ``drift_detector`` attached, each scrape additionally feeds
         the detector one window of observable deltas (cumulative telemetry
@@ -747,22 +825,30 @@ class OnlineAdmissionEngine:
             tel = self._cs.tel
             tel_copy = (jax.tree.map(jnp.copy, tel)
                         if tel is not None else None)
-            idle, busy = self._pump_idle_s, self._pump_busy_s
             eng = {
                 "n_requests": self.decisions,
                 "n_flushes": self.n_flushes,
                 "n_refreshes": self.n_refreshes,
                 "n_ticks": self.ticks,
                 "queue_depth": self.n_pending,
-                "pump_idle_fraction": (idle / (idle + busy)
-                                       if idle + busy > 0 else 0.0),
+                "pump_busy_seconds": self._pump_busy_s,
                 "decision_latency_seconds": self._hist_latency.snapshot(),
+                "queue_wait_seconds": self._hist_queue_wait.snapshot(),
+                "answer_seconds": self._hist_answer.snapshot(),
+                "decide_wait_seconds": self._hist_decide_wait.snapshot(),
+                "part_host_seconds": self._part_host_s,
+                "tick_host_seconds": self._hist_tick_host.snapshot(),
+                "lock_wait_seconds": {
+                    taker: {"sum": s, "count": n}
+                    for taker, (s, n) in self._lock_wait.items()},
                 "flush_batch_size": self._hist_batch.snapshot(),
                 "deadline_misses": self.deadline_misses,
                 "flush_slo_ms": (0.0 if self.flush_slo_s is None
                                  else self.flush_slo_s * 1e3),
                 "n_shards": self.n_shards,
+                "time_s": time.monotonic(),
             }
+        eng["compiled_programs"] = self._compiled_programs()
         snap = {"engine": eng}
         if tel_copy is not None:
             snap["telemetry"] = telemetry_summary(tel_copy)
