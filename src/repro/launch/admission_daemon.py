@@ -40,6 +40,10 @@ Where a decision's time goes, on the same endpoint (all ``repro_admission_``):
     how often it was taken; flushes waiting on ticks show here.
   * ``pump_busy_seconds_total``: seconds the flush thread spent flushing;
     its rate is the thread's busy share, near 1 when it is saturated.
+  * ``device_calls_total{step="tick"|"part"}`` and
+    ``device_call_steps_total{step}``: the engine's programs run plus its
+    explicit copies in and out, and the steps counted; 3 per tick with a
+    refresh (2 without) and 3 per flush part.
   * ``compiled_programs{step}`` (gauge): programs compiled by each jitted
     step; a step that grows while serving is recompiling on the hot path.
 

@@ -152,8 +152,9 @@ def snapshot_to_prometheus(snap: dict) -> str:
     the occupancy/staleness histograms become gauges per bin; the host-side
     engine histograms (decision latency and its phases, tick lock hold,
     flush batch size) are exposed as native Prometheus histograms, the
-    host phase sums as ``*_seconds_total`` counters, plus queue-depth and
-    compiled-program gauges.
+    host phase sums as ``*_seconds_total`` counters, the device calls of
+    each tick and flush part as ``device_calls_total`` with its step count,
+    plus queue-depth and compiled-program gauges.
     """
     mets: list[Metric] = []
 
@@ -191,6 +192,16 @@ def snapshot_to_prometheus(snap: dict) -> str:
             "repro_admission_lock_acquires_total", "counter",
             "Outermost acquires of the engine state lock, by taker",
             [({"taker": t}, w["count"]) for t, w in sorted(waits.items())]))
+    calls = eng.get("device_calls_total", {})
+    if calls:
+        mets.append(Metric(
+            "repro_admission_device_calls_total", "counter",
+            "Engine programs run plus explicit host<->device copies, by step",
+            [({"step": s}, c["sum"]) for s, c in sorted(calls.items())]))
+        mets.append(Metric(
+            "repro_admission_device_call_steps_total", "counter",
+            "Engine steps counted in device_calls_total, by step",
+            [({"step": s}, c["count"]) for s, c in sorted(calls.items())]))
     gauge("queue_depth", "Pending requests in the micro-batch queue",
           [({}, eng.get("queue_depth", 0))])
     gauge("shard_count", "Devices the slot table is sharded over",

@@ -48,8 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.belief import belief_from_prior, observe_initial_size
-from ..core.policies import PolicyParams
+from ..core.belief import GammaBelief, belief_from_prior, observe_initial_size
+from ..core.policies import DecisionDiag, PolicyParams
 from ..core.processes import DeploymentParams, sample_params
 from ..obs.counters import WindowStats, fold_window, telemetry_summary
 from ..obs.export import HostHistogram, log_buckets
@@ -114,6 +114,54 @@ class ExternalEvents(NamedTuple):
     spont_death: jax.Array
     scaleout_cores: jax.Array
     n_scaleouts: jax.Array
+
+
+class _Window(NamedTuple):
+    """What the engine carries on the device from one step to the next
+    besides the ``CoreState``: the open window and the run's counters that
+    the decides fold. Scalars, or ``[C]`` per cluster for fleets."""
+
+    open: jax.Array        # bool: a window is open, its close still due
+    out: StepOutcome       # the open window's dynamics
+    util: jax.Array        # decision-time utilization
+    acc: jax.Array         # requests admitted in the open window
+    rej: jax.Array         # requests rejected in the open window
+    rej_all: jax.Array     # fleets: requests routed nowhere, whole run
+    tick: jax.Array        # int32: windows ticked so far
+    key: jax.Array         # the open window's step key
+    route_key: Optional[jax.Array]   # fleets: fold_in(key, C)
+
+
+#: a packed flush part is ``[PART_ROWS, width]`` float32: an
+#: ``ArrivalStream`` lane's leaves in tree order (``params``, ``c0``,
+#: ``bel``, ``bel_alt``; ``n_arrivals`` left out) and then ``valid``
+_LANE_TREE = jax.tree.structure(ArrivalStream(
+    params=DeploymentParams(0, 0, 0), c0=0, bel=GammaBelief(*[0] * 6),
+    bel_alt=GammaBelief(*[0] * 6), n_arrivals=None))
+PART_ROWS = _LANE_TREE.num_leaves + 1
+
+
+def _unpack_part(packed: jax.Array):
+    """``(ArrivalStream, valid)`` of a packed flush part, inside a program."""
+    valid = packed[-1] != 0.0
+    stream = jax.tree.unflatten(_LANE_TREE, list(packed[:-1]))
+    return stream._replace(n_arrivals=valid.astype(jnp.int32)), valid
+
+
+def _unpack_events(packed: jax.Array) -> ExternalEvents:
+    """The ``ExternalEvents`` of a packed ``[4, ...]`` float32 tick buffer,
+    inside a program."""
+    return ExternalEvents(core_deaths=packed[0], spont_death=packed[1] != 0.0,
+                          scaleout_cores=packed[2], n_scaleouts=packed[3])
+
+
+def _pack_events(events: ExternalEvents) -> np.ndarray:
+    """One window's events as one ``[4, ...]`` float32 host buffer. The
+    counts are whole numbers below 2**24, which float32 holds exactly."""
+    packed = np.empty((4,) + np.shape(events.core_deaths), np.float32)
+    for row, field in zip(packed, events):
+        row[...] = field
+    return packed
 
 
 class OnlineAdmissionEngine:
@@ -206,18 +254,24 @@ class OnlineAdmissionEngine:
                             else float(flush_slo_ms) / 1e3)
         self.deadline_misses = 0
         self._flush_cost_s = 0.0    # EWMA of observed flush wall time
-        self._base_key = jax.random.PRNGKey(seed)
         self.naive = naive
         self.width = int(micro_batch or base.max_arrivals)
         self.n_c = self.cfg.n_clusters if self.fleet else 1
-        self._caps = (jnp.asarray(self.cfg.capacities, jnp.float32)
-                      if self.fleet else
-                      jnp.asarray(base.capacity, jnp.float32))
+        # every input of the engine's programs lives where they run: the
+        # sharded engine's on each device of its mesh, so no call moves them
+        self._sharding = (None if mesh is None else
+                          jax.sharding.NamedSharding(
+                              mesh, jax.sharding.PartitionSpec()))
+        put = lambda x: jax.device_put(x, self._sharding)
+        self._base_key = put(jax.random.PRNGKey(seed))
+        self._caps = put(jnp.asarray(
+            self.cfg.capacities if self.fleet else base.capacity,
+            jnp.float32))
         if self.fleet:
             from ..sim.routing import LeastUtilizedRouter
             self.router = LeastUtilizedRouter() if router is None else router
             policy = broadcast_policy(policy, self.n_c)
-        self.policy = policy
+        self.policy = put(policy)
 
         # -- engine state (owned by the engine thread) ----------------------
         cs = self.core.init()
@@ -225,17 +279,21 @@ class OnlineAdmissionEngine:
             cs = jax.tree.map(
                 lambda x: jnp.broadcast_to(x, (self.n_c,) + x.shape), cs)
         self._cs: CoreState = cs
-        self._out: Optional[StepOutcome] = None   # current window's dynamics
-        self._util = None                         # decision-time utilization
-        self._step_key = None                     # key of the open window
-        self._acc = 0.0                           # window accept/reject
-        self._rej = 0.0                           # counts ([C] for fleets)
-        self._rej_all = 0.0                       # fleet: routed-nowhere
+        zero = jnp.zeros((self.n_c,) if self.fleet else (), jnp.float32)
+        self._win = put(_Window(
+            open=jnp.zeros((), bool),
+            out=StepOutcome(util=zero, failed=zero, n_requests=zero,
+                            departed=zero),
+            util=zero, acc=zero, rej=zero, rej_all=jnp.zeros((), jnp.float32),
+            tick=jnp.zeros((), jnp.int32), key=self._base_key,
+            route_key=self._base_key if self.fleet else None))
+        self._open = False                        # mirrors _win.open
         self.ticks = 0
         self.decisions = 0
         self._util_trace: list = []
         self._fail_trace: list = []
-        self._pad = self._pad_template()
+        self._pad_part = self._pad_template(1 if naive else self.width)
+        self._j_metrics: dict = {}                # horizon -> assembly
 
         # -- micro-batch front-end ------------------------------------------
         self._pending: list = []                  # [(Arrival, Future, t_sub)]
@@ -270,6 +328,8 @@ class OnlineAdmissionEngine:
         self._part_host_s = 0.0                   # flush parts minus waits
         self._decide_wait_s = 0.0                 # the last decide's wait
         self._lock_wait = {"flush": [0.0, 0], "tick": [0.0, 0]}
+        # device calls (programs run, explicit copies in and out) per step
+        self._device_calls = {"tick": [0, 0], "part": [0, 0]}
         self.n_flushes = 0
         self.n_refreshes = 0
         self._pump_busy_s = 0.0
@@ -296,100 +356,138 @@ class OnlineAdmissionEngine:
 
     def _build_jit(self):
         core, cfg, n_c, caps = self.core, self.base, self.n_c, self._caps
+        fleet, base_key = self.fleet, self._base_key
+        f32 = jnp.float32
 
-        if not self.fleet:
-            self._j_refresh = jax.jit(core.refresh_aggregates,
-                                      donate_argnums=(0,))
-            self._j_tick = jax.jit(lambda k, cs: core.apply_events(k, cs),
-                                   donate_argnums=(1,))
-            self._j_ingest = jax.jit(self._ingest_one, donate_argnums=(1,))
+        def close(cs, win):
+            """The open window's close, as the offline scan ends a step; a
+            no-op when no window is open."""
+            slots, util_end = _accumulate_step(cs.slots, win.out, win.acc,
+                                               win.rej, cfg.dt)
+            slots = jax.tree.map(lambda new, old: jnp.where(win.open, new, old),
+                                 slots, cs.slots)
+            return cs._replace(slots=slots), util_end
 
-            def decide(policy, cs, util, batch, valid):
+        def opened(win, out, key):
+            zero = jnp.zeros_like(win.acc)
+            return win._replace(
+                open=jnp.ones((), bool), out=out, util=out.util, acc=zero,
+                rej=zero, tick=win.tick + 1, key=key,
+                route_key=jax.random.fold_in(key, n_c) if fleet else None)
+
+        apply_observed = (jax.vmap(self._apply_observed) if fleet
+                          else self._apply_observed)
+
+        # one program per tick besides the refresh: the previous window's
+        # close, this window's dynamics and its key. The observed-events
+        # program keeps the name the benchmark's trace reduction counts
+        # ticks by (jit__ingest_one)
+        def _ingest_one(caps, cs, xs):
+            packed, win = xs
+            cs, util_end = close(cs, win)
+            cs, out = apply_observed(caps, cs, _unpack_events(packed))
+            # derive from the engine's seed chain: PRNGKey(tick) would be
+            # identical across engines, fleet clusters and restarts,
+            # perfectly correlating any downstream belief noise
+            key = jax.random.fold_in(base_key, win.tick)
+            return cs, (opened(win, out, key), util_end)
+
+        def _simulate_one(key, cs, win):
+            cs, util_end = close(cs, win)
+            if fleet:
+                cs, out = jax.vmap(
+                    lambda cap, k, cs_c: core.apply_events(k, cs_c, cap))(
+                        caps, _cluster_step_keys(key, n_c), cs)
+            else:
+                cs, out = core.apply_events(key, cs)
+            return cs, (opened(win, out, key), util_end)
+
+        self._j_ingest = jax.jit(_ingest_one, donate_argnums=(1,))
+        self._j_tick = jax.jit(_simulate_one, donate_argnums=(1,))
+        refresh = (jax.vmap(core.refresh_aggregates) if fleet
+                   else core.refresh_aggregates)
+        self._j_refresh = jax.jit(refresh, donate_argnums=(0,))
+
+        # metrics()' own close. No donation: the engine keeps referencing
+        # the aggregate buffers of the CoreState it passes in
+        def close_window(cs, win):
+            cs, util_end = close(cs, win)
+            return cs.slots, util_end, win._replace(open=jnp.zeros((), bool))
+
+        self._j_close = jax.jit(close_window)
+
+        # a decide takes one packed part and returns the host's results as
+        # one array: the accept mask (with the traced decide's diagnostics
+        # stacked under it); the window's counters stay on the device
+        def folded(cs, win, accept, valid):
+            # post-placement utilization, so a second flush inside the
+            # same window admits against the already-placed arrivals
+            util = jnp.sum(cs.slots.cores * cs.slots.alive.astype(f32))
+            n_acc = jnp.sum(accept.astype(f32))
+            n_rej = jnp.sum(valid.astype(f32)) - n_acc
+            return win._replace(util=util, acc=win.acc + n_acc,
+                                rej=win.rej + n_rej)
+
+        if not fleet:
+            def decide(policy, cs, win, packed):
+                batch, valid = _unpack_part(packed)
                 cand = core.candidates(batch)
-                cs, accept = core.decide_batch(policy, cs, util, cand,
+                cs, accept = core.decide_batch(policy, cs, win.util, cand,
                                                batch, valid)
-                # post-placement utilization, so a second flush inside the
-                # same window admits against the already-placed arrivals
-                util = jnp.sum(cs.slots.cores
-                               * cs.slots.alive.astype(jnp.float32))
-                return cs, accept, util
+                return cs, folded(cs, win, accept, valid), accept
 
-            self._j_decide = jax.jit(decide, donate_argnums=(1,))
-
-            def decide_traced(policy, cs, util, batch, valid):
+            def decide_traced(policy, cs, win, packed):
+                batch, valid = _unpack_part(packed)
                 cand = core.candidates(batch)
                 cs, accept, diag = core.decide_batch_traced(
-                    policy, cs, util, cand, batch, valid)
-                util = jnp.sum(cs.slots.cores
-                               * cs.slots.alive.astype(jnp.float32))
-                return cs, accept, util, diag
+                    policy, cs, win.util, cand, batch, valid)
+                rows = jnp.stack([jnp.broadcast_to(x, accept.shape).astype(f32)
+                                  for x in (accept, *diag)])
+                return cs, folded(cs, win, accept, valid), rows
 
-            self._j_decide_traced = jax.jit(decide_traced, donate_argnums=(1,))
-
-            def naive_decide(policy, cs, util, batch, valid):
-                # ablation: full O(slots * grid) aggregate recompute, then a
-                # width-1 decision — the cost of admission without the
-                # incrementally-maintained aggregate
-                cs = core.refresh_aggregates(cs)
-                return decide(policy, cs, util, batch, valid)
-
-            self._j_naive = jax.jit(naive_decide, donate_argnums=(1,))
+            self._j_decide_traced = jax.jit(decide_traced,
+                                            donate_argnums=(1, 2))
         else:
-            self._j_refresh = jax.jit(jax.vmap(core.refresh_aggregates),
-                                      donate_argnums=(0,))
-
-            def fleet_tick(key, cs):
-                keys_c = _cluster_step_keys(key, n_c)
-                return jax.vmap(
-                    lambda cap, k, cs_c: core.apply_events(k, cs_c, cap))(
-                        caps, keys_c, cs)
-
-            self._j_tick = jax.jit(fleet_tick, donate_argnums=(1,))
-            self._j_ingest = jax.jit(
-                jax.vmap(self._ingest_one, in_axes=(0, 0, 0)),
-                donate_argnums=(1,))
-
-            def fleet_decide(policy, cs, util, batch, valid, route_key,
-                             rej_all):
+            def fleet_decide(policy, cs, win, packed):
                 from ..sim.routing import RouteContext
 
+                batch, valid = _unpack_part(packed)
                 cand = core.candidates(batch)
-                assign = self.router.route(route_key, RouteContext(
+                assign = self.router.route(win.route_key, RouteContext(
                     cand=cand, c0=batch.c0, valid=valid, agg_el=cs.agg_el,
-                    agg_vl=cs.agg_vl, util=util, capacities=caps,
+                    agg_vl=cs.agg_vl, util=win.util, capacities=caps,
                     policy=policy))
                 assign = jnp.clip(assign, 0, n_c)   # sentinel n_c = nowhere
                 mask = valid[None, :] & (
                     assign[None, :] == jnp.arange(n_c)[:, None])
-                rej_all = rej_all + jnp.sum(
-                    (valid & (assign == n_c)).astype(jnp.float32))
+                rej_all = win.rej_all + jnp.sum(
+                    (valid & (assign == n_c)).astype(f32))
                 cs, accept = jax.vmap(
                     lambda pol_c, cs_c, u_c, m_c: core.decide_batch(
                         pol_c, cs_c, u_c, cand, batch, m_c))(
-                            policy, cs, util, mask)
-                n_acc = jnp.sum(accept.astype(jnp.float32), axis=1)
-                n_rej = jnp.sum(mask.astype(jnp.float32), axis=1) - n_acc
-                util = jnp.sum(cs.slots.cores
-                               * cs.slots.alive.astype(jnp.float32), axis=-1)
-                return cs, accept, util, n_acc, n_rej, rej_all
+                            policy, cs, win.util, mask)
+                n_acc = jnp.sum(accept.astype(f32), axis=1)
+                n_rej = jnp.sum(mask.astype(f32), axis=1) - n_acc
+                util = jnp.sum(cs.slots.cores * cs.slots.alive.astype(f32),
+                               axis=-1)
+                win = win._replace(util=util, acc=win.acc + n_acc,
+                                   rej=win.rej + n_rej, rej_all=rej_all)
+                return cs, win, jnp.any(accept, axis=0)
 
-            self._j_decide = jax.jit(fleet_decide, donate_argnums=(1,))
+            decide = fleet_decide
 
-            def fleet_naive(policy, cs, util, batch, valid, route_key,
-                            rej_all):
-                cs = jax.vmap(core.refresh_aggregates)(cs)
-                return fleet_decide(policy, cs, util, batch, valid,
-                                    route_key, rej_all)
+        def naive_decide(policy, cs, win, packed):
+            # ablation: full O(slots * grid) aggregate recompute, then a
+            # width-1 decision — the cost of admission without the
+            # incrementally-maintained aggregate
+            return decide(policy, refresh(cs), win, packed)
 
-            self._j_naive = jax.jit(fleet_naive, donate_argnums=(1,))
+        # the window is donated too: what a decide leaves unchanged then
+        # aliases its input on the device instead of being copied
+        self._j_decide = jax.jit(decide, donate_argnums=(1, 2))
+        self._j_naive = jax.jit(naive_decide, donate_argnums=(1, 2))
 
-        # no donation: the engine keeps referencing the aggregate buffers of
-        # the CoreState it passes in (only the slot accumulators change)
-        self._j_close = jax.jit(
-            lambda cs, out, n_acc, n_rej: _accumulate_step(
-                cs.slots, out, n_acc, n_rej, cfg.dt))
-
-    def _ingest_one(self, capacity, cs: CoreState, ev: ExternalEvents):
+    def _apply_observed(self, capacity, cs: CoreState, ev: ExternalEvents):
         """Apply one cluster's observed events: the simulated
         ``_step_dynamics`` arithmetic with the random event draw replaced by
         the observation (same death clamping, greedy slot-order grants
@@ -446,37 +544,52 @@ class OnlineAdmissionEngine:
         blocked ``agg_refresh_steps`` schedule says so, then applies this
         window's deaths / scale-out grants / belief updates — simulated from
         the fitted processes under ``key``, or observed via ``events``.
+
+        On the device that is the refresh, when due, and one program for
+        the rest (``_j_ingest`` or ``_j_tick``); observed events travel in
+        one packed float32 copy, made before the state lock is taken.
         """
         if (key is None) == (events is None):
             raise ValueError("tick() needs exactly one of key= or events=")
+        calls = 1                                 # the tick's program
+        if events is not None:
+            with annotate("repro.engine.tick.events"):
+                packed = self._put(_pack_events(events))
+            calls += 1
+        elif not isinstance(key, jax.Array):
+            key = self._put(key)
+            calls += 1
         with self._locked("tick"), annotate("repro.engine.tick"):
             t0 = time.monotonic()
-            with annotate("repro.engine.tick.close"):
-                self._close_window()
             if self.ticks % self.k_refresh == 0 and not self.naive:
                 with annotate("repro.engine.refresh"):
                     self._cs = self._j_refresh(self._cs)
                 self.n_refreshes += 1
-            if events is not None:
-                with annotate("repro.engine.tick.events"):
-                    ev = jax.tree.map(jnp.asarray, events)
-                with annotate("repro.engine.tick.ingest"):
-                    self._cs, self._out = self._j_ingest(self._caps,
-                                                         self._cs, ev)
-                    # derive from the engine's seed chain: PRNGKey(self.ticks)
-                    # here would be identical across engines, fleet clusters,
-                    # and restarts, perfectly correlating any downstream
-                    # belief noise
-                    self._step_key = jax.random.fold_in(self._base_key,
-                                                        self.ticks)
-            else:
-                with annotate("repro.engine.tick.ingest"):
-                    self._cs, self._out = self._j_tick(key, self._cs)
-                self._step_key = key
-            self._util = self._out.util
-            self._acc = self._rej = 0.0
+                calls += 1
+            with annotate("repro.engine.tick.ingest"):
+                if events is not None:
+                    self._cs, (win, util_end) = self._j_ingest(
+                        self._caps, self._cs, (packed, self._win))
+                else:
+                    self._cs, (win, util_end) = self._j_tick(
+                        key, self._cs, self._win)
+            with annotate("repro.engine.tick.close"):
+                if self._open:
+                    self._util_trace.append(util_end)
+                    self._fail_trace.append(self._win.out.failed)
+                self._win, self._open = win, True
             self.ticks += 1
+            self._count_calls("tick", calls)
             self._hist_tick_host.observe(time.monotonic() - t0)
+
+    def _put(self, x):
+        """One explicit host-to-device copy, to where the programs run."""
+        return jax.device_put(x, self._sharding)
+
+    def _count_calls(self, step: str, n: int) -> None:
+        stat = self._device_calls[step]
+        stat[0] += n
+        stat[1] += 1
 
     @contextlib.contextmanager
     def _locked(self, taker: str):
@@ -497,18 +610,16 @@ class OnlineAdmissionEngine:
 
     def _close_window(self):
         with self._state_lock:
-            if self._out is None:
+            if not self._open:
                 return
-            slots, util_end = self._j_close(
-                self._cs, self._out, jnp.asarray(self._acc, jnp.float32),
-                jnp.asarray(self._rej, jnp.float32))
+            failed = self._win.out.failed
+            slots, util_end, self._win = self._j_close(self._cs, self._win)
             self._cs = self._cs._replace(slots=slots)
             self._util_trace.append(util_end)
-            self._fail_trace.append(self._out.failed)
-            self._out = None
-            # zero the folded window counters so a second close (metrics()
-            # followed by tick()) cannot double-count them
-            self._acc = self._rej = 0.0
+            self._fail_trace.append(failed)
+            # the next tick's program does not close a closed window again,
+            # so metrics() followed by tick() cannot double-count it
+            self._open = False
 
     # ------------------------------------------------- micro-batch frontend
 
@@ -532,13 +643,13 @@ class OnlineAdmissionEngine:
         by one on the naive ablation path); resolves their futures. Returns
         the number of decisions made.
 
-        The whole drain runs under ``_state_lock``: the ``_out`` check and
+        The whole drain runs under ``_state_lock``: the open-window check and
         the decides it gates are one critical section, so a concurrent
         ``tick()``/``metrics()`` cannot close the window mid-flight. A chunk
         that raises fails every remaining future with the exception instead
         of leaving callers blocked forever."""
         with self._locked("flush"):
-            if self._out is None:
+            if not self._open:
                 raise RuntimeError("flush() before the first tick()")
             with self._lock:
                 pending, self._pending = self._pending, []
@@ -583,13 +694,11 @@ class OnlineAdmissionEngine:
         """Record one decided micro-batch chunk: submit→decision latency
         into the host histogram, plus (when a tracer is attached) one
         structured record per decision with the policy score/threshold from
-        the traced decide path. The diag arrays are materialized to numpy
-        once per chunk before the record loop — indexing the device arrays
-        per record would cost one device→host sync per decision."""
+        the traced decide path. The diag arrays reach the host in the
+        part's one fetch, stacked under its accept mask — indexing device
+        arrays per record would cost one device→host sync per decision."""
         t_dec = time.monotonic()
         diag = self._last_diag
-        if diag is not None and self.tracer is not None:
-            diag = jax.tree.map(np.asarray, diag)
         with self._state_lock:
             self._hist_batch.observe(float(len(part)))
             for j, ((_, _, t_sub), ok) in enumerate(zip(part, accept)):
@@ -614,75 +723,66 @@ class OnlineAdmissionEngine:
 
     def decide_slice(self, stream_t: ArrivalStream,
                      valid: np.ndarray) -> np.ndarray:
-        """Decide one pre-stacked width-``micro_batch`` arrival slice (the
-        zero-copy path the equivalence tests and benchmarks drive; ``submit``
-        + ``flush`` stack onto exactly this). Returns the ``[A]`` accept
-        mask (for fleets: OR over the per-cluster ``[C, A]`` decisions)."""
-        valid = jnp.asarray(valid)
-        fn = self._j_naive if self.naive else self._j_decide
+        """Decide one pre-stacked arrival slice of any width (the path the
+        equivalence tests and benchmarks drive; ``submit`` + ``flush`` pack
+        onto the same program). Returns the ``[A]`` accept mask (for
+        fleets: OR over the per-cluster ``[C, A]`` decisions)."""
+        valid = np.asarray(valid, bool)
+        leaves = jax.tree.leaves(stream_t._replace(n_arrivals=None))
+        packed = np.empty((PART_ROWS, valid.shape[0]), np.float32)
+        for row, leaf in zip(packed, leaves):
+            row[:] = np.asarray(leaf)
+        packed[-1] = valid
+        return self._decide_packed(packed, int(np.count_nonzero(valid)))
+
+    def _decide_packed(self, packed: np.ndarray, n_valid: int) -> np.ndarray:
+        """Decide one packed ``[PART_ROWS, width]`` part: one copy in, one
+        program, one copy out."""
+        traced = self.tracer is not None and not self.naive and not self.fleet
+        fn = (self._j_naive if self.naive else
+              self._j_decide_traced if traced else self._j_decide)
+        packed = self._put(packed)
         with self._state_lock:
             # checked under the lock: a concurrent tick()/metrics() closing
-            # the window flips _out to None mid-flight otherwise
-            if self._out is None:
+            # the window flips _open mid-flight otherwise
+            if not self._open:
                 raise RuntimeError("decide_slice() before the first tick()")
+            self._cs, self._win, out = fn(self.policy, self._cs, self._win,
+                                          packed)
+            t_ret = time.monotonic()
+            with annotate("repro.engine.flush.wait"):
+                out = jax.device_get(out)
+            self._observe_decide_wait(time.monotonic() - t_ret)
             self._last_diag = None
-            if not self.fleet:
-                if self.tracer is not None and not self.naive:
-                    self._cs, accept, self._util, self._last_diag = \
-                        self._j_decide_traced(self.policy, self._cs,
-                                              self._util, stream_t, valid)
-                else:
-                    self._cs, accept, self._util = fn(
-                        self.policy, self._cs, self._util, stream_t, valid)
-                t_ret = time.monotonic()
-                with annotate("repro.engine.flush.wait"):
-                    accept = np.asarray(accept)
-                self._observe_decide_wait(time.monotonic() - t_ret)
-                n_acc = float(np.sum(accept))
-                self._acc += n_acc
-                self._rej += float(np.sum(np.asarray(valid))) - n_acc
-            else:
-                rkey = jax.random.fold_in(self._step_key, self.n_c)
-                (self._cs, accept_c, self._util, n_acc, n_rej,
-                 self._rej_all) = fn(
-                    self.policy, self._cs, self._util, stream_t, valid, rkey,
-                    jnp.asarray(self._rej_all, jnp.float32))
-                t_ret = time.monotonic()
-                with annotate("repro.engine.flush.wait"):
-                    n_acc = np.asarray(n_acc)
-                self._observe_decide_wait(time.monotonic() - t_ret)
-                self._acc = self._acc + n_acc
-                self._rej = self._rej + np.asarray(n_rej)
-                accept = np.asarray(jnp.any(accept_c, axis=0))
-            self.decisions += int(np.sum(np.asarray(valid)))
-        return accept
+            if traced:
+                self._last_diag = DecisionDiag(fits=out[1] != 0.0,
+                                               score=out[2], threshold=out[3])
+                out = out[0] != 0.0
+            self.decisions += n_valid
+            self._count_calls("part", 3)
+        return out
 
     def _observe_decide_wait(self, wait: float) -> None:
         self._decide_wait_s = wait
         self._hist_decide_wait.observe(wait)
 
     def _decide(self, arrivals: list) -> np.ndarray:
-        """Stack ``Arrival`` tickets into one padded fixed-width slice."""
+        """Write ``Arrival`` tickets straight into one padded packed part."""
         n = len(arrivals)
-        width = 1 if self.naive else self.width
         with annotate("repro.engine.flush.stack"):
-            lanes = [self._lane(a) for a in arrivals]
-            lanes += [self._pad] * (width - n)
-            batch = jax.tree.map(lambda *xs: np.stack(xs), *lanes)
-            valid = np.arange(width) < n
-        return self.decide_slice(batch, valid)[:n]
+            packed = self._pad_part.copy()
+            for j, a in enumerate(arrivals):
+                packed[:-1, j] = (*a.params, a.c0, *a.bel, *a.bel_alt)
+            packed[-1, :n] = 1.0
+        return self._decide_packed(packed, n)[:n]
 
-    def _lane(self, a: Arrival) -> ArrivalStream:
-        return ArrivalStream(params=a.params, c0=np.float32(a.c0),
-                             bel=a.bel, bel_alt=a.bel_alt,
-                             n_arrivals=np.int32(1))
-
-    def _pad_template(self) -> ArrivalStream:
-        bel = jax.tree.map(np.asarray, belief_from_prior(self.base.priors, ()))
-        params = DeploymentParams(lam=np.float32(0.0), mu=np.float32(1.0),
-                                  sig=np.float32(0.0))
-        return ArrivalStream(params=params, c0=np.float32(1.0), bel=bel,
-                             bel_alt=bel, n_arrivals=np.int32(0))
+    def _pad_template(self, width: int) -> np.ndarray:
+        """A packed part of ``width`` invalid lanes (placeholder arrivals)."""
+        bel = belief_from_prior(self.base.priors, ())
+        lane = (0.0, 1.0, 0.0, 1.0, *bel, *bel)       # lam, mu, sig, c0, ...
+        packed = np.zeros((PART_ROWS, width), np.float32)
+        packed[:-1] = np.asarray(lane, np.float32)[:, None]
+        return packed
 
     # ------------------------------------------------------------ async pump
 
@@ -792,21 +892,35 @@ class OnlineAdmissionEngine:
         else:
             shape = (0, self.n_c) if self.fleet else (0,)
             util_trace = fail_trace = jnp.zeros(shape)
-        if not self.fleet:
-            return jax.tree.map(np.asarray, _run_metrics(
-                self.base, self._cs.slots, util_trace, fail_trace,
-                horizon_hours=horizon))
-        return jax.tree.map(np.asarray, _fleet_metrics(
-            self.base, self._caps, self._cs.slots, util_trace.T,
-            fail_trace.T, jnp.asarray(self._rej_all, jnp.float32),
-            horizon_hours=horizon))
+        return jax.tree.map(np.asarray, self._assemble(horizon)(
+            self._cs.slots, util_trace, fail_trace, self._win.rej_all))
+
+    def _assemble(self, horizon: float):
+        """The metrics assembly compiled as the offline scans compile
+        theirs, with the horizon and the capacities constants of the
+        program: run op by op, a division by them can round one ulp away
+        from the compiled one."""
+        fn = self._j_metrics.get(horizon)
+        if fn is None:
+            base, caps = self.base, self._caps
+            if self.fleet:
+                fn = jax.jit(lambda slots, util, fail, rej_all: _fleet_metrics(
+                    base, caps, slots, util.T, fail.T, rej_all,
+                    horizon_hours=horizon))
+            else:
+                fn = jax.jit(lambda slots, util, fail, _: _run_metrics(
+                    base, slots, util, fail, horizon_hours=horizon))
+            self._j_metrics[horizon] = fn
+        return fn
 
     def metrics_snapshot(self) -> dict:
         """Non-blocking observability snapshot: engine counters, the host
         phase histograms and sums (decision latency, queue wait, answer
         time, decide wait, tick lock hold, flush batch size, lock waits,
-        the pump's busy seconds), the programs each jitted step has
-        compiled, and (with ``cfg.telemetry``) the device telemetry rider's
+        the pump's busy seconds), the device calls of each tick and flush
+        part (``device_calls_total``: programs run plus explicit copies in
+        and out, summed, and the steps counted), the programs each jitted
+        step has compiled, and (with ``cfg.telemetry``) the device telemetry rider's
         summary. ``time_s`` is the snapshot's ``time.monotonic``, so two
         snapshots give rates over the interval between them.
 
@@ -841,6 +955,9 @@ class OnlineAdmissionEngine:
                 "lock_wait_seconds": {
                     taker: {"sum": s, "count": n}
                     for taker, (s, n) in self._lock_wait.items()},
+                "device_calls_total": {
+                    step: {"sum": s, "count": n}
+                    for step, (s, n) in self._device_calls.items()},
                 "flush_batch_size": self._hist_batch.snapshot(),
                 "deadline_misses": self.deadline_misses,
                 "flush_slo_ms": (0.0 if self.flush_slo_s is None

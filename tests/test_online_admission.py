@@ -20,8 +20,9 @@ from repro.core import (AZURE_PRIORS, SECOND, ZEROTH, fleet_policy,
 from repro.serve import (Arrival, ExternalEvents, OnlineAdmissionEngine,
                          default_policy_param, format_operating_derived,
                          load_operating_point, operating_row_name)
-from repro.sim import (FleetConfig, LeastUtilizedRouter, SimConfig,
-                       draw_arrival_stream, make_fleet_run, make_run)
+from repro.sim import (FleetConfig, LeastUtilizedRouter, RandomRouter,
+                       SimConfig, draw_arrival_stream, make_fleet_run,
+                       make_run)
 
 CFG = SimConfig(capacity=500.0, arrival_rate=0.08, horizon_hours=30 * 24.0,
                 dt=24.0, max_slots=96, max_arrivals=4, d_points=8,
@@ -63,16 +64,44 @@ def _assert_metrics_equal(off, on):
                                           err_msg=name)
 
 
-def test_single_cluster_matches_offline_bit_for_bit():
-    pol = make_policy(SECOND, rho=0.05, capacity=CFG.capacity)
+@pytest.mark.parametrize("k_refresh", [1, 3])
+def test_single_cluster_matches_offline_bit_for_bit(k_refresh):
+    """Every tick's close, dynamics and (on the K schedule) refresh, and
+    every decide, against the offline scan: with K = 3 two ticks in three
+    run without the refresh."""
+    cfg = CFG._replace(agg_refresh_steps=k_refresh)
+    pol = make_policy(SECOND, rho=0.05, capacity=cfg.capacity)
     key = jax.random.PRNGKey(1)
-    m_off, acc_off = make_run(CFG, GRID, SECOND,
+    m_off, acc_off = make_run(cfg, GRID, SECOND,
                               record_decisions=True)(key, pol)
-    stream, keys = _offline_inputs(key, CFG)
-    eng = OnlineAdmissionEngine(CFG, GRID, SECOND, pol)
+    stream, keys = _offline_inputs(key, cfg)
+    eng = OnlineAdmissionEngine(cfg, GRID, SECOND, pol)
     acc_on = _drive(eng, stream, keys)
     np.testing.assert_array_equal(acc_on, np.asarray(acc_off))
     _assert_metrics_equal(m_off, eng.metrics())
+
+
+@pytest.mark.parametrize("router,k_refresh", [
+    (RandomRouter(), 1),           # routes by the key the tick folds
+    (LeastUtilizedRouter(), 3),
+])
+def test_small_fleet_matches_offline_bit_for_bit(router, k_refresh):
+    """A two-cluster fleet against ``make_fleet_run``: the route key each
+    tick folds on the device (``fold_in(step_key, C)``), the routed-nowhere
+    and per-cluster counters the decides carry there, and the close inside
+    the tick's program."""
+    base = SMALL._replace(arrival_rate=0.3, agg_refresh_steps=k_refresh)
+    fleet = FleetConfig(base=base, capacities=(300.0, 200.0))
+    pol = fleet_policy(SECOND, capacities=fleet.capacities, rho=0.05)
+    key = jax.random.PRNGKey(6)
+    m_off, acc_off, _ = make_fleet_run(
+        fleet, GRID, SECOND, router=router, record_decisions=True)(key, pol)
+    stream, keys = _offline_inputs(key, base)
+    eng = OnlineAdmissionEngine(fleet, GRID, SECOND, pol, router=router)
+    acc_on = _drive(eng, stream, keys)
+    np.testing.assert_array_equal(acc_on, np.any(np.asarray(acc_off), axis=1))
+    _assert_metrics_equal(m_off, eng.metrics())
+    assert np.asarray(acc_off).any()
 
 
 @pytest.mark.slow
@@ -140,11 +169,14 @@ def test_background_pump_resolves_futures():
     assert eng.decisions == len(futs)
 
 
-def test_external_event_ingestion():
-    """Production path: observed departures/scale-outs via tick(events=)."""
-    pol = make_policy(ZEROTH, threshold=SMALL.capacity,
-                      capacity=SMALL.capacity)
-    eng = OnlineAdmissionEngine(SMALL, GRID, ZEROTH, pol, micro_batch=4)
+@pytest.mark.parametrize("k_refresh", [1, 3])
+def test_external_event_ingestion(k_refresh):
+    """Production path: observed departures/scale-outs via tick(events=),
+    with and without the refresh in the same tick (K = 3: the two event
+    ticks refresh nothing)."""
+    cfg = SMALL._replace(agg_refresh_steps=k_refresh)
+    pol = make_policy(ZEROTH, threshold=cfg.capacity, capacity=cfg.capacity)
+    eng = OnlineAdmissionEngine(cfg, GRID, ZEROTH, pol, micro_batch=4)
     eng.tick(jax.random.PRNGKey(0))
     fut = eng.submit(Arrival.draw(jax.random.PRNGKey(5), SMALL))
     eng.flush()
@@ -172,6 +204,8 @@ def test_external_event_ingestion():
     m = eng.metrics()
     assert int(m.alive_end) == 0
     assert int(m.n_departed) == 1
+    assert int(m.total_requests) == 1        # no window closed twice
+    assert eng.n_refreshes == (3 if k_refresh == 1 else 1)
 
 
 def test_tick_and_flush_protocol_errors():
@@ -259,11 +293,15 @@ def test_event_path_keys_derive_from_seed_chain():
     e_a2 = OnlineAdmissionEngine(SMALL, GRID, ZEROTH, pol, seed=0)
     for e in (e_a, e_b, e_a2):
         e.tick(events=ev)
-    k_a, k_b, k_a2 = (np.asarray(e._step_key) for e in (e_a, e_b, e_a2))
+    k_a, k_b, k_a2 = (np.asarray(e._win.key) for e in (e_a, e_b, e_a2))
     assert np.array_equal(k_a, k_a2)          # restart-reproducible
     assert not np.array_equal(k_a, k_b)       # engines decorrelate
     e_a.tick(events=ev)
-    assert not np.array_equal(np.asarray(e_a._step_key), k_a)  # advances
+    assert not np.array_equal(np.asarray(e_a._win.key), k_a)  # advances
+    # the chain is fold_in(PRNGKey(seed), tick), folded inside the tick
+    for t, k in enumerate((k_a, np.asarray(e_a._win.key))):
+        np.testing.assert_array_equal(
+            k, np.asarray(jax.random.fold_in(jax.random.PRNGKey(0), t)))
 
 
 def test_close_window_counter_idempotence():

@@ -263,11 +263,11 @@ def test_engine_tracer_writes_jsonl(engine_run):
 
 
 def test_tracer_diag_materialized_once_per_chunk():
-    """Regression (PR 9): ``_trace_part`` materializes the decision diag to
-    numpy once per chunk before the record loop. Asserted structurally (the
-    tracer receives numpy scalars, never device arrays — each device-array
-    index is one device->host sync) and by timing (the chunk-level
-    materialization is cheaper than per-record device reads)."""
+    """Regression: the decision diag reaches the host once per chunk,
+    before the record loop. Asserted structurally: the tracer receives
+    numpy scalars, never device arrays (each device-array index is one
+    device->host sync), and the diag is numpy, fetched with the part's
+    accept mask in its one copy out."""
     recorded = []
 
     class SpyTracer:
@@ -291,17 +291,14 @@ def test_tracer_diag_materialized_once_per_chunk():
 
     diag = eng._last_diag
     assert diag is not None
-    n_rep = 10
-    t0 = time.perf_counter()
-    for _ in range(n_rep):
-        d = jax.tree.map(np.asarray, diag)    # what _trace_part does
-        [float(d.score[j]) for j in range(width)]
-    once_per_chunk = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for _ in range(n_rep):
-        [float(diag.score[j]) for j in range(width)]   # the old per-record
-    per_record = time.perf_counter() - t0              # device reads
-    assert once_per_chunk < per_record, (once_per_chunk, per_record)
+    # the chunk's diagnostics reached the host in the part's one fetch,
+    # stacked under its accept mask: no record reads a device array
+    for leaf in diag:
+        assert isinstance(leaf, np.ndarray) and leaf.shape == (width,)
+    part = eng.metrics_snapshot()["engine"]["device_calls_total"]["part"]
+    assert part == {"sum": 3, "count": 1}
+    assert [r["score"] for r in recorded] == list(diag.score)
+    assert [r["fits"] for r in recorded] == list(diag.fits)
 
 
 def test_snapshot_off_has_no_telemetry_key():
