@@ -33,8 +33,10 @@ Key Gamma integrals (mu ~ Gamma(a, b), rate parameterization):
 
 H and K stay valid by analytic continuation for a+p < 0 (the case for the
 fitted Azure priors, where a + nu - 1 = -0.0163): we evaluate them through
-``exp(gammaln(a+p+1) - gammaln(a)) / (a+p)`` and ``expm1`` so the removable
-singularity at a+p = 0 never produces a NaN.
+``Gamma(a+p+1)/Gamma(a) / (a+p)`` and ``expm1`` so the removable
+singularity at a+p = 0 never produces a NaN. Every ratio R is formed by
+``core.belief.log_gamma_ratio``, accurate at the large shapes a heavily
+observed belief reaches.
 """
 from __future__ import annotations
 
@@ -43,9 +45,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.scipy.special import gammaln
 
-from .belief import GammaBelief
+from .belief import GammaBelief, log_gamma_ratio
 from .processes import PopulationPriors
 
 _EPS = 1e-12
@@ -69,7 +70,7 @@ class MomentCurves(NamedTuple):
 
 def _g(a, b, p, t):
     """E[mu^p e^(-t mu)]; requires a + p > 0 (true for p in {0, nu, 2nu})."""
-    logr = gammaln(a + p) - gammaln(a)
+    logr = log_gamma_ratio(a, p)
     return jnp.exp(logr - p * jnp.log(b) - (a + p) * jnp.log1p(t / b))
 
 
@@ -77,7 +78,7 @@ def _h(a, b, p, t):
     """E[mu^p (1 - e^(-t mu))], valid for a + p > -1 (analytic continuation)."""
     z = a + p
     z = jnp.where(jnp.abs(z) < _EPS, _EPS, z)
-    logr1 = gammaln(z + 1.0) - gammaln(a)  # log Gamma(a+p+1)/Gamma(a), arg > 0
+    logr1 = log_gamma_ratio(a, p + 1.0)  # log Gamma(a+p+1)/Gamma(a)
     bracket = -jnp.expm1(-z * jnp.log1p(t / b))
     return jnp.exp(logr1 - p * jnp.log(b)) * bracket / z
 
@@ -86,7 +87,7 @@ def _k(a, b, p, t):
     """E[mu^p (1 - e^(-t mu))²], valid for a + p > -2 if a + 2p' terms converge."""
     z = a + p
     z = jnp.where(jnp.abs(z) < _EPS, _EPS, z)
-    logr1 = gammaln(z + 1.0) - gammaln(a)
+    logr1 = log_gamma_ratio(a, p + 1.0)
     l1 = jnp.log1p(t / b)
     l2 = jnp.log1p(2.0 * t / b)
     bracket = -2.0 * jnp.expm1(-z * l1) + jnp.expm1(-z * l2)
@@ -249,7 +250,7 @@ def moment_curves(
 # The admission policies only consume the cluster-wide sums over alive slots,
 # sum_s E[L^s_t] and sum_s V[L^s_t] — the per-slot [S, N] curves are an
 # intermediate. ``aggregate_moment_curves`` computes the masked sums directly:
-# per-slot Gamma-continuation factors are packed once (the gammaln-heavy part,
+# per-slot Gamma-continuation factors are packed once (the Gamma-ratio part,
 # shared with the Pallas kernel's packing in kernels/moment_curves/ops.py),
 # curve blocks of ``block_size`` slots are evaluated with shared log1p
 # subexpressions and matmul interpolation, and each block is reduced into the
@@ -261,9 +262,9 @@ def moment_curves(
 class PackedBelief(NamedTuple):
     """Per-slot scalar factors of the moment-curve closed forms.
 
-    Everything that needs gammaln (no Pallas lowering, and the costliest
-    per-slot scalar work) is precomputed here; curve evaluation from a
-    PackedBelief touches only log1p/expm1/exp.
+    Everything that needs a Gamma ratio (the costliest per-slot scalar
+    work, kept out of the Pallas kernel) is precomputed here; curve
+    evaluation from a PackedBelief touches only log1p/expm1/exp.
     """
 
     a: jax.Array        # mu posterior shape
@@ -291,13 +292,14 @@ def pack_belief(bel: GammaBelief, cores: jax.Array,
 
     z1 = a + nu - 1.0
     z1 = jnp.where(jnp.abs(z1) < _EPS, _EPS, z1)
-    rh1 = jnp.exp(gammaln(z1 + 1.0) - gammaln(a)
-                  - (nu - 1.0) * jnp.log(b)) / z1
+    log_b = jnp.log(b)
+    r_nu = log_gamma_ratio(a, nu)
+    rh1 = jnp.exp(r_nu - (nu - 1.0) * log_b) / z1
     z2 = a + 2.0 * nu - 2.0
     z2 = jnp.where(jnp.abs(z2) < _EPS, _EPS, z2)
-    rk = jnp.exp(gammaln(z2 + 1.0) - gammaln(a)
-                 - (2.0 * nu - 2.0) * jnp.log(b)) / z2
-    e_mu_nu = jnp.exp(gammaln(a + nu) - gammaln(a) - nu * jnp.log(b))
+    rk = jnp.exp(log_gamma_ratio(a, 2.0 * nu - 1.0)
+                 - (2.0 * nu - 2.0) * log_b) / z2
+    e_mu_nu = jnp.exp(r_nu - nu * log_b)
     return PackedBelief(
         a=a, b=b, cores=cores.astype(a.dtype), eu=el * e_s1,
         eu2=el2 * e_s1_sq, el=el, es1=e_s1, ess2=e_ss2, rh1=rh1, z1=z1,

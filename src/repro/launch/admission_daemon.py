@@ -46,6 +46,9 @@ Where a decision's time goes, on the same endpoint (all ``repro_admission_``):
     refresh (2 without) and 3 per flush part.
   * ``compiled_programs{step}`` (gauge): programs compiled by each jitted
     step; a step that grows while serving is recompiling on the hot path.
+  * with ``--tenants``, ``tenant_lookups_total{outcome="informed"|"cold"}``:
+    decided requests whose tenant's row held an admitted deployment, and
+    the rest; ``tenant_rows_informed`` (gauge): the rows that hold one.
 
 Scaling: ``--shards N`` shards the slot table over N devices (one engine,
 bit-for-bit the single-device decisions — see ``sim.core.slot_mesh``);
@@ -54,11 +57,17 @@ engine's deadline scheduler, which fires partial micro-batches before any
 pending request exceeds its L-millisecond decision SLO (misses surface as
 ``repro_admission_deadline_misses_total`` on ``/metrics``).
 
+Tenants: ``--tenants T`` has every request name one of T tenants (the
+optional ``tenant`` field of each ``serve.Arrival``; -1, the default,
+names none and is decided on the population prior). The engine then keeps
+a device tenant table of each tenant's pooled history and decides each
+request on its tenant's posterior (one unsharded cluster only).
+
 Usage:
   PYTHONPATH=src python -m repro.launch.admission_daemon --hours 2000 \
       --capacity 4096 [--policy second|first|zeroth] [--fleet 2048,2048] \
       [--param RHO_OR_THRESHOLD] [--micro-batch 8] [--metrics-port 9109] \
-      [--throttle 0.05] [--shards 8] [--flush-slo-ms 50]
+      [--throttle 0.05] [--shards 8] [--flush-slo-ms 50] [--tenants 5958]
 """
 from __future__ import annotations
 
@@ -103,7 +112,8 @@ def build_engine(args):
     base = SimConfig(capacity=args.capacity, arrival_rate=args.arrival_rate,
                      horizon_hours=args.hours, dt=args.dt,
                      max_slots=args.max_slots, max_arrivals=args.micro_batch,
-                     priors=AZURE_PRIORS, telemetry=telemetry)
+                     priors=AZURE_PRIORS, telemetry=telemetry,
+                     n_tenants=getattr(args, "tenants", 0) or 0)
     grid = geometric_grid(args.dt, args.hours * 3, 32)
 
     param = args.param
@@ -252,6 +262,13 @@ def build_parser() -> argparse.ArgumentParser:
                     help="shard the slot table over N devices (single "
                          "cluster only; decisions stay bit-for-bit equal "
                          "to the unsharded engine)")
+    ap.add_argument("--tenants", type=int, default=0, metavar="T",
+                    help="every request names one of T tenants (each "
+                         "request's optional tenant field; -1 names none "
+                         "and is decided on the population prior): the "
+                         "engine keeps a device table of each tenant's "
+                         "pooled history and decides each request on its "
+                         "tenant's posterior; one unsharded cluster only")
     ap.add_argument("--flush-slo-ms", type=float, default=None, metavar="MS",
                     help="decision-latency SLO: run the deadline-aware "
                          "flush scheduler instead of per-tick flushing")

@@ -154,7 +154,8 @@ def snapshot_to_prometheus(snap: dict) -> str:
     flush batch size) are exposed as native Prometheus histograms, the
     host phase sums as ``*_seconds_total`` counters, the device calls of
     each tick and flush part as ``device_calls_total`` with its step count,
-    plus queue-depth and compiled-program gauges.
+    with tenants the tenant lookups by outcome and the informed rows, plus
+    queue-depth and compiled-program gauges.
     """
     mets: list[Metric] = []
 
@@ -202,6 +203,16 @@ def snapshot_to_prometheus(snap: dict) -> str:
             "repro_admission_device_call_steps_total", "counter",
             "Engine steps counted in device_calls_total, by step",
             [({"step": s}, c["count"]) for s, c in sorted(calls.items())]))
+    lookups = eng.get("tenant_lookups_total")
+    if lookups:
+        mets.append(Metric(
+            "repro_admission_tenant_lookups_total", "counter",
+            "Decided requests' tenant lookups, by outcome (informed: the "
+            "tenant's row held an admitted deployment)",
+            [({"outcome": o}, v) for o, v in sorted(lookups.items())]))
+        gauge("tenant_rows_informed",
+              "Tenant rows holding at least one admitted deployment",
+              [({}, eng.get("tenant_rows_informed", 0))])
     gauge("queue_depth", "Pending requests in the micro-batch queue",
           [({}, eng.get("queue_depth", 0))])
     gauge("shard_count", "Devices the slot table is sharded over",

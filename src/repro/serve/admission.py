@@ -51,7 +51,7 @@ import numpy as np
 from ..core.belief import GammaBelief, belief_from_prior, observe_initial_size
 from ..core.policies import DecisionDiag, PolicyParams
 from ..core.processes import DeploymentParams, sample_params
-from ..obs.counters import WindowStats, fold_window, telemetry_summary
+from ..obs.counters import telemetry_summary
 from ..obs.export import HostHistogram, log_buckets
 from ..obs.tracing import DecisionTracer, annotate
 from ..sim.core import (ArrivalStream, CoreState, FleetConfig, SimConfig,
@@ -71,12 +71,18 @@ class Arrival:
     *simulate* the deployment's future dynamics (benchmarks, the daemon's
     synthetic load); a production deployment's real events arrive through
     ``tick(events=...)`` instead and ``params`` is dead weight there.
+
+    ``tenant`` names the request's tenant for a configuration with
+    ``n_tenants``: its belief is then formed on the device from the
+    tenant's row, and ``bel``/``bel_alt`` are not read. -1 (the default)
+    names none, and the request is decided on the population prior.
     """
 
     c0: float
     bel: object                    # GammaBelief scalars (provider's prior)
     bel_alt: object                # second mixture component (§7 unlabeled)
     params: object                 # DeploymentParams scalars
+    tenant: int = -1               # tenant id (tenant configurations)
 
     @staticmethod
     def from_stream(stream: ArrivalStream, t: int, a: int) -> "Arrival":
@@ -84,7 +90,9 @@ class Arrival:
         return Arrival(c0=float(pick(stream.c0)),
                        bel=jax.tree.map(pick, stream.bel),
                        bel_alt=jax.tree.map(pick, stream.bel_alt),
-                       params=jax.tree.map(pick, stream.params))
+                       params=jax.tree.map(pick, stream.params),
+                       tenant=(-1 if stream.tenant is None
+                               else int(pick(stream.tenant))))
 
     @staticmethod
     def draw(key: jax.Array, cfg: SimConfig) -> "Arrival":
@@ -130,6 +138,8 @@ class _Window(NamedTuple):
     tick: jax.Array        # int32: windows ticked so far
     key: jax.Array         # the open window's step key
     route_key: Optional[jax.Array]   # fleets: fold_in(key, C)
+    lookups: Optional[jax.Array] = None   # tenants: [2] lookups informed
+                                          # and cold, whole run
 
 
 #: a packed flush part is ``[PART_ROWS, width]`` float32: an
@@ -139,12 +149,21 @@ _LANE_TREE = jax.tree.structure(ArrivalStream(
     params=DeploymentParams(0, 0, 0), c0=0, bel=GammaBelief(*[0] * 6),
     bel_alt=GammaBelief(*[0] * 6), n_arrivals=None))
 PART_ROWS = _LANE_TREE.num_leaves + 1
+#: a tenant configuration's part carries the tenant id in place of the
+#: beliefs, which its decide forms from the tenant table:
+#: ``[TENANT_PART_ROWS, width]`` (``params``, ``c0``, ``tenant``, ``valid``)
+_TENANT_LANE_TREE = jax.tree.structure(ArrivalStream(
+    params=DeploymentParams(0, 0, 0), c0=0, bel=None, bel_alt=None,
+    n_arrivals=None, tenant=0))
+TENANT_PART_ROWS = _TENANT_LANE_TREE.num_leaves + 1
 
 
-def _unpack_part(packed: jax.Array):
+def _unpack_part(packed: jax.Array, tree=_LANE_TREE):
     """``(ArrivalStream, valid)`` of a packed flush part, inside a program."""
     valid = packed[-1] != 0.0
-    stream = jax.tree.unflatten(_LANE_TREE, list(packed[:-1]))
+    stream = jax.tree.unflatten(tree, list(packed[:-1]))
+    if stream.tenant is not None:
+        stream = stream._replace(tenant=stream.tenant.astype(jnp.int32))
     return stream._replace(n_arrivals=valid.astype(jnp.int32)), valid
 
 
@@ -245,6 +264,13 @@ class OnlineAdmissionEngine:
                 "shards= shards one cluster's slot table over devices; "
                 "fleet engines already spread state over the cluster axis "
                 "— run one sharded engine per cluster instead")
+        self.tenants = base.n_tenants > 0
+        if self.tenants and (self.fleet or self.n_shards > 1):
+            raise ValueError(
+                "n_tenants > 0 is served as one unsharded cluster: the "
+                "tenant table is neither routed over a fleet nor sharded "
+                "over devices (fleets and shards= are out of scope for "
+                "tenant configurations)")
         mesh = slot_mesh(self.n_shards) if self.n_shards > 1 else None
         self.core = make_admission_core(base, grid, policy_kind, mesh=mesh)
         self.k_refresh = base.agg_refresh_steps
@@ -286,12 +312,14 @@ class OnlineAdmissionEngine:
                             departed=zero),
             util=zero, acc=zero, rej=zero, rej_all=jnp.zeros((), jnp.float32),
             tick=jnp.zeros((), jnp.int32), key=self._base_key,
-            route_key=self._base_key if self.fleet else None))
+            route_key=self._base_key if self.fleet else None,
+            lookups=jnp.zeros(2, jnp.float32) if self.tenants else None))
         self._open = False                        # mirrors _win.open
         self.ticks = 0
         self.decisions = 0
         self._util_trace: list = []
         self._fail_trace: list = []
+        self._lane_tree = _TENANT_LANE_TREE if self.tenants else _LANE_TREE
         self._pad_part = self._pad_template(1 if naive else self.width)
         self._j_metrics: dict = {}                # horizon -> assembly
 
@@ -375,8 +403,12 @@ class OnlineAdmissionEngine:
                 rej=zero, tick=win.tick + 1, key=key,
                 route_key=jax.random.fold_in(key, n_c) if fleet else None)
 
-        apply_observed = (jax.vmap(self._apply_observed) if fleet
-                          else self._apply_observed)
+        def apply_observed(cap, cs, ev):
+            return core.apply_observed(cs, ev, cap)
+
+        if fleet:
+            apply_observed = jax.vmap(apply_observed)
+        unpack = lambda packed: _unpack_part(packed, self._lane_tree)
 
         # one program per tick besides the refresh: the previous window's
         # close, this window's dynamics and its key. The observed-events
@@ -419,31 +451,39 @@ class OnlineAdmissionEngine:
         # a decide takes one packed part and returns the host's results as
         # one array: the accept mask (with the traced decide's diagnostics
         # stacked under it); the window's counters stay on the device
-        def folded(cs, win, accept, valid):
+        def folded(cs, win, accept, valid, informed):
             # post-placement utilization, so a second flush inside the
             # same window admits against the already-placed arrivals
             util = jnp.sum(cs.slots.cores * cs.slots.alive.astype(f32))
+            n_valid = jnp.sum(valid.astype(f32))
             n_acc = jnp.sum(accept.astype(f32))
-            n_rej = jnp.sum(valid.astype(f32)) - n_acc
-            return win._replace(util=util, acc=win.acc + n_acc,
-                                rej=win.rej + n_rej)
+            n_rej = n_valid - n_acc
+            win = win._replace(util=util, acc=win.acc + n_acc,
+                               rej=win.rej + n_rej)
+            if informed is not None:
+                n_inf = jnp.sum((informed & valid).astype(f32))
+                win = win._replace(lookups=win.lookups + jnp.stack(
+                    [n_inf, n_valid - n_inf]))
+            return win
 
         if not fleet:
             def decide(policy, cs, win, packed):
-                batch, valid = _unpack_part(packed)
+                batch, valid = unpack(packed)
+                batch, informed = core.lookup(cs, batch)
                 cand = core.candidates(batch)
                 cs, accept = core.decide_batch(policy, cs, win.util, cand,
                                                batch, valid)
-                return cs, folded(cs, win, accept, valid), accept
+                return cs, folded(cs, win, accept, valid, informed), accept
 
             def decide_traced(policy, cs, win, packed):
-                batch, valid = _unpack_part(packed)
+                batch, valid = unpack(packed)
+                batch, informed = core.lookup(cs, batch)
                 cand = core.candidates(batch)
                 cs, accept, diag = core.decide_batch_traced(
                     policy, cs, win.util, cand, batch, valid)
                 rows = jnp.stack([jnp.broadcast_to(x, accept.shape).astype(f32)
                                   for x in (accept, *diag)])
-                return cs, folded(cs, win, accept, valid), rows
+                return cs, folded(cs, win, accept, valid, informed), rows
 
             self._j_decide_traced = jax.jit(decide_traced,
                                             donate_argnums=(1, 2))
@@ -451,7 +491,7 @@ class OnlineAdmissionEngine:
             def fleet_decide(policy, cs, win, packed):
                 from ..sim.routing import RouteContext
 
-                batch, valid = _unpack_part(packed)
+                batch, valid = unpack(packed)
                 cand = core.candidates(batch)
                 assign = self.router.route(win.route_key, RouteContext(
                     cand=cand, c0=batch.c0, valid=valid, agg_el=cs.agg_el,
@@ -486,52 +526,6 @@ class OnlineAdmissionEngine:
         # aliases its input on the device instead of being copied
         self._j_decide = jax.jit(decide, donate_argnums=(1, 2))
         self._j_naive = jax.jit(naive_decide, donate_argnums=(1, 2))
-
-    def _apply_observed(self, capacity, cs: CoreState, ev: ExternalEvents):
-        """Apply one cluster's observed events: the simulated
-        ``_step_dynamics`` arithmetic with the random event draw replaced by
-        the observation (same death clamping, greedy slot-order grants
-        against capacity, and conjugate belief updates)."""
-        from ..core.belief import update_on_events
-
-        cfg, state = self.base, cs.slots
-        alive_f = state.alive.astype(jnp.float32)
-        deaths = jnp.minimum(ev.core_deaths.astype(jnp.float32),
-                             state.cores) * alive_f
-        exposure = state.cores * cfg.dt * alive_f
-        cores = state.cores - deaths
-        cores = jnp.where(ev.spont_death & state.alive, 0.0, cores)
-        alive = state.alive & (cores > 0.0)
-        departed = jnp.sum((state.alive & ~alive).astype(jnp.float32))
-        alive_f = alive.astype(jnp.float32)
-
-        req = ev.scaleout_cores.astype(jnp.float32) * alive_f
-        n_req = ev.n_scaleouts.astype(jnp.float32) * alive_f
-        util = jnp.sum(cores * alive_f)
-        grant = (util + jnp.cumsum(req)) <= capacity
-        cores = cores + jnp.where(grant, req, 0.0)
-        failed = jnp.sum(jnp.where(~grant, n_req, 0.0))
-        util = jnp.sum(cores * alive_f)
-
-        bel = update_on_events(
-            state.bel, core_deaths=deaths, exposure_core_hours=exposure,
-            n_scaleouts=n_req, scaleout_cores=req,
-            alive_hours=cfg.dt * alive_f, priors=cfg.priors)
-        tel = cs.tel
-        if cfg.telemetry:
-            spont = jnp.sum((ev.spont_death & state.alive)
-                            .astype(jnp.float32))
-            tel = fold_window(tel, util, capacity, WindowStats(
-                core_deaths=jnp.sum(deaths),
-                exposure_core_hours=jnp.sum(exposure),
-                n_scaleouts=jnp.sum(n_req),
-                scaleout_cores=jnp.sum(req),
-                alive_hours=cfg.dt * jnp.sum(alive_f),
-                spont_deaths=spont, departed=departed))
-        cs = cs._replace(slots=state._replace(alive=alive, cores=cores,
-                                              bel=bel), tel=tel)
-        return cs, StepOutcome(util=util, failed=failed,
-                               n_requests=jnp.sum(n_req), departed=departed)
 
     # ------------------------------------------------------- step protocol
 
@@ -728,16 +722,21 @@ class OnlineAdmissionEngine:
         onto the same program). Returns the ``[A]`` accept mask (for
         fleets: OR over the per-cluster ``[C, A]`` decisions)."""
         valid = np.asarray(valid, bool)
-        leaves = jax.tree.leaves(stream_t._replace(n_arrivals=None))
-        packed = np.empty((PART_ROWS, valid.shape[0]), np.float32)
+        lanes = stream_t._replace(n_arrivals=None)
+        if self.tenants:
+            lanes = lanes._replace(bel=None, bel_alt=None)
+        leaves = jax.tree.leaves(lanes)
+        packed = np.empty((self._lane_tree.num_leaves + 1, valid.shape[0]),
+                          np.float32)
         for row, leaf in zip(packed, leaves):
             row[:] = np.asarray(leaf)
         packed[-1] = valid
         return self._decide_packed(packed, int(np.count_nonzero(valid)))
 
     def _decide_packed(self, packed: np.ndarray, n_valid: int) -> np.ndarray:
-        """Decide one packed ``[PART_ROWS, width]`` part: one copy in, one
-        program, one copy out."""
+        """Decide one packed ``[PART_ROWS, width]`` part
+        (``TENANT_PART_ROWS`` with tenants): one copy in, one program, one
+        copy out."""
         traced = self.tracer is not None and not self.naive and not self.fleet
         fn = (self._j_naive if self.naive else
               self._j_decide_traced if traced else self._j_decide)
@@ -771,16 +770,23 @@ class OnlineAdmissionEngine:
         n = len(arrivals)
         with annotate("repro.engine.flush.stack"):
             packed = self._pad_part.copy()
-            for j, a in enumerate(arrivals):
-                packed[:-1, j] = (*a.params, a.c0, *a.bel, *a.bel_alt)
+            if self.tenants:
+                for j, a in enumerate(arrivals):
+                    packed[:-1, j] = (*a.params, a.c0, a.tenant)
+            else:
+                for j, a in enumerate(arrivals):
+                    packed[:-1, j] = (*a.params, a.c0, *a.bel, *a.bel_alt)
             packed[-1, :n] = 1.0
         return self._decide_packed(packed, n)[:n]
 
     def _pad_template(self, width: int) -> np.ndarray:
         """A packed part of ``width`` invalid lanes (placeholder arrivals)."""
-        bel = belief_from_prior(self.base.priors, ())
-        lane = (0.0, 1.0, 0.0, 1.0, *bel, *bel)       # lam, mu, sig, c0, ...
-        packed = np.zeros((PART_ROWS, width), np.float32)
+        if self.tenants:
+            lane = (0.0, 1.0, 0.0, 1.0, -1.0)         # lam, mu, sig, c0, tenant
+        else:
+            bel = belief_from_prior(self.base.priors, ())
+            lane = (0.0, 1.0, 0.0, 1.0, *bel, *bel)   # lam, mu, sig, c0, ...
+        packed = np.zeros((len(lane) + 1, width), np.float32)
         packed[:-1] = np.asarray(lane, np.float32)[:, None]
         return packed
 
@@ -913,6 +919,16 @@ class OnlineAdmissionEngine:
             self._j_metrics[horizon] = fn
         return fn
 
+    def tenant_table(self) -> Optional[np.ndarray]:
+        """A host copy of the tenant table (``[n_tenants,
+        len(core.belief.TENANT_COLUMNS)]``, each compensated sum added up
+        in float64), or ``None`` without tenants."""
+        if not self.tenants:
+            return None
+        with self._state_lock:
+            table = jnp.copy(self._cs.tenants)
+        return np.asarray(jax.device_get(table), np.float64).sum(axis=0)
+
     def metrics_snapshot(self) -> dict:
         """Non-blocking observability snapshot: engine counters, the host
         phase histograms and sums (decision latency, queue wait, answer
@@ -920,9 +936,13 @@ class OnlineAdmissionEngine:
         the pump's busy seconds), the device calls of each tick and flush
         part (``device_calls_total``: programs run plus explicit copies in
         and out, summed, and the steps counted), the programs each jitted
-        step has compiled, and (with ``cfg.telemetry``) the device telemetry rider's
-        summary. ``time_s`` is the snapshot's ``time.monotonic``, so two
-        snapshots give rates over the interval between them.
+        step has compiled, with tenants the decides' tenant lookups
+        (``tenant_lookups_total``: informed, where the tenant's row held an
+        admitted deployment, and cold) and the rows that hold one
+        (``tenant_rows_informed``), and (with ``cfg.telemetry``) the device
+        telemetry rider's summary. ``time_s`` is the snapshot's
+        ``time.monotonic``, so two snapshots give rates over the interval
+        between them.
 
         With a ``drift_detector`` attached, each scrape additionally feeds
         the detector one window of observable deltas (cumulative telemetry
@@ -939,6 +959,9 @@ class OnlineAdmissionEngine:
             tel = self._cs.tel
             tel_copy = (jax.tree.map(jnp.copy, tel)
                         if tel is not None else None)
+            if self.tenants:
+                lookups = jnp.copy(self._win.lookups)
+                sizes = jnp.copy(self._cs.tenants[0, :, -1])
             eng = {
                 "n_requests": self.decisions,
                 "n_flushes": self.n_flushes,
@@ -966,6 +989,12 @@ class OnlineAdmissionEngine:
                 "time_s": time.monotonic(),
             }
         eng["compiled_programs"] = self._compiled_programs()
+        if self.tenants:
+            informed, cold = np.asarray(jax.device_get(lookups)).tolist()
+            eng["tenant_lookups_total"] = {"informed": informed,
+                                           "cold": cold}
+            eng["tenant_rows_informed"] = int(np.count_nonzero(
+                jax.device_get(sizes)))
         snap = {"engine": eng}
         if tel_copy is not None:
             snap["telemetry"] = telemetry_summary(tel_copy)

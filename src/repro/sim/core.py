@@ -18,6 +18,9 @@ arrive — the same decision machinery must therefore run both *offline*
       - ``refresh_aggregates(cs)``      full aggregate recompute from slots
       - ``apply_events(key, cs)``       one ``dt``-hour step of deaths /
                                         scale-out grants / belief updates
+      - ``apply_observed(cs, ev)``      the same step from observed events
+      - ``lookup(cs, stream_t)``        arrivals' beliefs from their
+                                        tenants' rows (tenant configs)
       - ``candidates(stream_t)``        [A, N] candidate moment curves
       - ``decide_batch(policy, cs, …)`` sequential admission + slot
                                         placement + incremental fold
@@ -39,7 +42,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.belief import (GammaBelief, apply_pseudo_observations,
-                           belief_from_prior, observe_initial_size,
+                           belief_from_prior, fold_tenant_arrivals,
+                           fold_tenant_events, init_tenant_table,
+                           observe_initial_size, tenant_belief,
                            update_on_events)
 from ..core.moments import (F32, MomentCurves, aggregate_moment_curves,
                             masked_curve_reduction, moment_curves,
@@ -54,6 +59,9 @@ from ..core.processes import (DeploymentParams, PopulationPriors,
                               sample_step_events)
 
 GLOBAL, PSEUDO, MIX_LABELED, MIX_UNLABELED = "global", "pseudo", "labeled", "unlabeled"
+#: Zipf exponent of the tenants' shares of drawn arrivals (an assumption:
+#: the head tenant of the Azure trace's 5,958 sends 10.8 %)
+TENANT_ZIPF = 1.0
 AGG_FUSED, AGG_REFERENCE, AGG_KERNEL = "fused", "reference", "kernel"
 
 
@@ -88,6 +96,11 @@ class SimConfig(NamedTuple):
                                      # default) compiles the rider out
                                      # entirely — decisions and metrics are
                                      # bit-identical either way
+    n_tenants: int = 0               # > 0: every arrival names one of this
+                                     # many tenants, and its belief is the
+                                     # prior updated by the device-resident
+                                     # table of its tenant's pooled history
+                                     # (GLOBAL only; 0 compiles it out)
 
     @property
     def n_steps(self) -> int:
@@ -116,6 +129,13 @@ def _validate_config(cfg: SimConfig) -> SimConfig:
         raise ValueError(f"unknown prior_mode {cfg.prior_mode!r}")
     if cfg.agg_backend not in (AGG_FUSED, AGG_REFERENCE, AGG_KERNEL):
         raise ValueError(f"unknown agg_backend {cfg.agg_backend!r}")
+    if cfg.n_tenants < 0:
+        raise ValueError(f"n_tenants={cfg.n_tenants} must be >= 0")
+    if cfg.n_tenants and cfg.prior_mode != GLOBAL:
+        raise ValueError(
+            f"n_tenants={cfg.n_tenants} with prior_mode={cfg.prior_mode!r}: "
+            "a tenant's history is the information model, starting from "
+            "the population prior (prior_mode=GLOBAL)")
     if cfg.n_pseudo_obs < 0:
         raise ValueError(f"n_pseudo_obs={cfg.n_pseudo_obs} must be >= 0")
     if cfg.prior_mode != GLOBAL and cfg.n_pseudo_obs == 0:
@@ -181,6 +201,11 @@ def _validate_fleet_config(fcfg: FleetConfig) -> FleetConfig:
         raise ValueError(
             f"FleetConfig.capacities must be positive, got {fcfg.capacities}")
     _validate_config(fcfg.base)
+    if fcfg.base.n_tenants:
+        raise ValueError(
+            "FleetConfig with n_tenants > 0: the tenant table is not "
+            "routed over a fleet's clusters; serve a tenant configuration "
+            "as one cluster")
     return fcfg
 
 
@@ -206,6 +231,8 @@ class ArrivalStream(NamedTuple):
     bel: GammaBelief                 # provider's prior belief for the arrival
     bel_alt: GammaBelief             # second mixture component (unlabeled mode)
     n_arrivals: jax.Array            # [T] arrivals per step (already capped)
+    tenant: Optional[jax.Array] = None   # [T, A] int32 tenant ids, with
+                                         # cfg.n_tenants; None otherwise
 
 
 class ArrivalSource:
@@ -241,7 +268,18 @@ def draw_arrival_stream(key: jax.Array, cfg: SimConfig) -> ArrivalStream:
     n_arr = jnp.minimum(
         jax.random.poisson(kn, cfg.arrival_rate * cfg.dt, (t_steps,)), a_max
     )
-    params = sample_params(kp, cfg.priors, shape)
+    tenant = None
+    if cfg.n_tenants:
+        # every deployment of a tenant shares one scaling type, drawn once
+        # per tenant; tenants' shares of arrivals fall off as 1/rank^s
+        kt, ki = jax.random.split(kp)
+        types = sample_params(kt, cfg.priors, (cfg.n_tenants,))
+        rank = jnp.arange(1, cfg.n_tenants + 1, dtype=jnp.float32)
+        tenant = jax.random.categorical(
+            ki, -TENANT_ZIPF * jnp.log(rank), shape=shape)
+        params = jax.tree.map(lambda x: x[tenant], types)
+    else:
+        params = sample_params(kp, cfg.priors, shape)
     c0 = (1 + jax.random.poisson(kc, params.sig)).astype(jnp.float32)
 
     prior = belief_from_prior(cfg.priors, shape)
@@ -264,7 +302,7 @@ def draw_arrival_stream(key: jax.Array, cfg: SimConfig) -> ArrivalStream:
         bel_alt = apply_pseudo_observations(prior, obs_alt, cfg.priors)
     bel = observe_initial_size(bel, c0)
     return ArrivalStream(params=params, c0=c0, bel=bel, bel_alt=bel_alt,
-                         n_arrivals=n_arr)
+                         n_arrivals=n_arr, tenant=tenant)
 
 
 class SimState(NamedTuple):
@@ -282,6 +320,8 @@ class SimState(NamedTuple):
     arr_rejected: jax.Array
     slot_overflow: jax.Array
     n_departed: jax.Array
+    tenant: Optional[jax.Array] = None   # [S] int32 tenant of each slot's
+                                         # deployment, with cfg.n_tenants
 
 
 class CoreState(NamedTuple):
@@ -293,12 +333,15 @@ class CoreState(NamedTuple):
 
     ``tel`` is the optional telemetry rider (``obs.counters.TelemetryState``):
     ``None`` — an empty pytree node, adding no buffers to the compiled
-    programs — unless ``SimConfig(telemetry=True)``."""
+    programs — unless ``SimConfig(telemetry=True)``. ``tenants`` is the
+    ``[2, n_tenants, len(TENANT_COLUMNS)]`` compensated float32 tenant
+    table (``core.belief``), likewise ``None`` unless ``SimConfig.n_tenants``."""
 
     slots: SimState
     agg_el: jax.Array             # [N] aggregate E[L_n] over admitted slots
     agg_vl: jax.Array             # [N] aggregate V[L_n]
     tel: Optional["TelemetryState"] = None
+    tenants: Optional[jax.Array] = None
 
 
 class StepOutcome(NamedTuple):
@@ -331,6 +374,7 @@ def _init_state(cfg: SimConfig) -> SimState:
         arr_rejected=jnp.zeros(()),
         slot_overflow=jnp.zeros(()),
         n_departed=jnp.zeros(()),
+        tenant=jnp.zeros(s, jnp.int32) if cfg.n_tenants else None,
     )
 
 
@@ -369,6 +413,8 @@ def _place_arrivals(state: SimState, accept, stream_t: ArrivalStream, cfg: SimCo
     bel = jax.tree.map(lambda o, n: merge(o, n), state.bel, stream_t.bel)
     state = state._replace(alive=alive | placed, cores=cores, params=params,
                            bel=bel, slot_overflow=overflow)
+    if cfg.n_tenants:
+        state = state._replace(tenant=merge(state.tenant, stream_t.tenant))
     return state, placed_arrival
 
 
@@ -446,22 +492,34 @@ def _make_candidates_fn(cfg: SimConfig, grid: jax.Array, needs_moments: bool,
 
 
 def _step_dynamics(cfg: SimConfig, capacity, key, state: SimState,
-                   with_stats: bool = False):
-    """Steps 1–3 of one ``dt``-hour step for ONE cluster: deaths, scale-out
-    grants against ``capacity`` (a traced value — the fleet passes each
-    cluster's own), and conjugate belief updates.
+                   with_stats: bool = False, tenants=None):
+    """Steps 1–3 of one ``dt``-hour step for ONE cluster, with the window's
+    events drawn from the deployments' true processes under ``key``; the
+    rest is ``_apply_window``'s."""
+    ev = sample_step_events(key, state.params, state.cores, cfg.priors,
+                            cfg.dt, alive=state.alive)
+    return _apply_window(cfg, capacity, state, ev, with_stats, tenants)
 
-    Returns ``(state, util, failed, n_req_total, departed, stats)`` with the
-    slot arrays updated and the metric counters untouched (the caller
-    accumulates them after admission). ``stats`` is the window's observable
-    sufficient statistics (``WindowStats``) when ``with_stats`` — the
-    telemetry rider's drift-detector stream — else ``None``.
+
+def _apply_window(cfg: SimConfig, capacity, state: SimState, ev,
+                  with_stats: bool = False, tenants=None):
+    """Deaths, scale-out grants against ``capacity`` (a traced value — the
+    fleet passes each cluster's own) and conjugate belief updates of one
+    window's events ``ev`` (``core_deaths``, ``spont_death``,
+    ``scaleout_cores``, ``n_scaleouts`` per slot: drawn, or observed), and
+    with a tenant table the same observations folded into each slot's
+    tenant row.
+
+    Returns ``(state, tenants, util, failed, n_req_total, departed,
+    stats)`` with the slot arrays updated and the metric counters untouched
+    (the caller accumulates them after admission). ``stats`` is the
+    window's observable sufficient statistics (``WindowStats``) when
+    ``with_stats`` — the telemetry rider's drift-detector stream — else
+    ``None``.
     """
     alive_f = state.alive.astype(jnp.float32)
 
     # 1. deaths ---------------------------------------------------------
-    ev = sample_step_events(key, state.params, state.cores, cfg.priors,
-                            cfg.dt, alive=state.alive)
     deaths = jnp.minimum(ev.core_deaths.astype(jnp.float32), state.cores) * alive_f
     exposure = state.cores * cfg.dt * alive_f
     cores = state.cores - deaths
@@ -481,15 +539,13 @@ def _step_dynamics(cfg: SimConfig, capacity, key, state: SimState,
     util = jnp.sum(cores * alive_f)
 
     # 3. belief updates (requests are observed whether or not granted) ---
-    bel = update_on_events(
-        state.bel,
-        core_deaths=deaths,
-        exposure_core_hours=exposure,
-        n_scaleouts=n_req,
-        scaleout_cores=req,
-        alive_hours=cfg.dt * alive_f,
-        priors=cfg.priors,
-    )
+    observed = dict(core_deaths=deaths, exposure_core_hours=exposure,
+                    n_scaleouts=n_req, scaleout_cores=req,
+                    alive_hours=cfg.dt * alive_f)
+    bel = update_on_events(state.bel, priors=cfg.priors, **observed)
+    if tenants is not None:
+        with jax.named_scope("tenant_fold"):
+            tenants = fold_tenant_events(tenants, state.tenant, **observed)
     state = state._replace(alive=alive, cores=cores, bel=bel)
     stats = None
     if with_stats:
@@ -502,7 +558,7 @@ def _step_dynamics(cfg: SimConfig, capacity, key, state: SimState,
             spont_deaths=spont,
             departed=departed,
         )
-    return state, util, failed, jnp.sum(n_req), departed, stats
+    return state, tenants, util, failed, jnp.sum(n_req), departed, stats
 
 
 def slot_mesh(n_shards: int, devices=None):
@@ -538,6 +594,8 @@ class AdmissionCore(NamedTuple):
     init: Callable[[], CoreState]
     refresh_aggregates: Callable[[CoreState], CoreState]
     apply_events: Callable[..., tuple]
+    apply_observed: Callable[..., tuple]
+    lookup: Callable[..., tuple]
     candidates: Callable[[ArrivalStream], MomentCurves]
     decide_batch: Callable[..., tuple]
     decide_batch_traced: Callable[..., tuple]
@@ -547,7 +605,7 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
                         policy_kind: int, *, mesh=None) -> AdmissionCore:
     """Build the pure admission-core function bundle for one configuration.
 
-    All five functions are pure pytree -> pytree maps (no python state), so
+    All its functions are pure pytree -> pytree maps (no python state), so
     the offline drivers scan them, the fleet vmaps them over the cluster
     axis, and the online engine jits them individually with donated
     ``CoreState`` buffers — one implementation, three execution regimes.
@@ -570,10 +628,13 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
                                         curves_fn)
 
     def init() -> CoreState:
+        tenants = (init_tenant_table(cfg.n_tenants) if cfg.n_tenants
+                   else None)
         return CoreState(slots=_init_state(cfg),
                          agg_el=jnp.zeros((n_grid,)),
                          agg_vl=jnp.zeros((n_grid,)),
-                         tel=init_telemetry() if cfg.telemetry else None)
+                         tel=init_telemetry() if cfg.telemetry else None,
+                         tenants=tenants)
 
     def refresh_aggregates(cs: CoreState) -> CoreState:
         """Full aggregate recompute from the slot table (block boundary).
@@ -588,21 +649,45 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
                                       cs.slots.alive)
         return cs._replace(agg_el=agg_el, agg_vl=agg_vl, tel=tel)
 
+    def _stepped(cs, cap, window):
+        slots, tenants, util, failed, n_req, departed, stats = window
+        tel = cs.tel
+        if cfg.telemetry:
+            tel = fold_window(tel, util, cap, stats)
+        return cs._replace(slots=slots, tel=tel, tenants=tenants), \
+            StepOutcome(util=util, failed=failed, n_requests=n_req,
+                        departed=departed)
+
     def apply_events(key: jax.Array, cs: CoreState, capacity=None):
         """One ``dt``-hour step of cluster dynamics: deaths, scale-out
         grants against ``capacity`` (defaults to the config's own; the
         fleet passes each cluster's), and conjugate belief updates. The
         maintained aggregate is NOT touched — within-block staleness is the
         ``agg_refresh_steps`` contract. With telemetry the rider folds the
-        window's occupancy and observable sufficient statistics."""
+        window's occupancy and observable sufficient statistics; with
+        tenants the table folds each slot's observations."""
         cap = cfg.capacity if capacity is None else capacity
-        slots, util, failed, n_req, departed, stats = _step_dynamics(
-            cfg, cap, key, cs.slots, with_stats=cfg.telemetry)
-        tel = cs.tel
-        if cfg.telemetry:
-            tel = fold_window(tel, util, cap, stats)
-        return cs._replace(slots=slots, tel=tel), StepOutcome(
-            util=util, failed=failed, n_requests=n_req, departed=departed)
+        return _stepped(cs, cap, _step_dynamics(
+            cfg, cap, key, cs.slots, cfg.telemetry, cs.tenants))
+
+    def apply_observed(cs: CoreState, ev, capacity=None):
+        """``apply_events`` with the window's events observed (an
+        ``ExternalEvents``-shaped ``ev``) instead of drawn."""
+        cap = cfg.capacity if capacity is None else capacity
+        return _stepped(cs, cap, _apply_window(
+            cfg, cap, cs.slots, ev, cfg.telemetry, cs.tenants))
+
+    def lookup(cs: CoreState, stream_t: ArrivalStream):
+        """``(stream_t, informed)``: with tenants, each arrival's belief
+        formed from its tenant's row as the table stands
+        (``core.belief.tenant_belief``) and where that row held an admitted
+        deployment; without, the stream as it is and ``None``."""
+        if not cfg.n_tenants:
+            return stream_t, None
+        with jax.named_scope("tenant_lookup"):
+            bel, informed = tenant_belief(cs.tenants, stream_t.tenant,
+                                          stream_t.c0, cfg.priors)
+        return stream_t._replace(bel=bel, bel_alt=bel), informed
 
     def _decide_core(policy: PolicyParams, cs: CoreState, util,
                      cand: MomentCurves, stream_t: ArrivalStream, valid,
@@ -625,8 +710,13 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
         if cfg.telemetry:
             tel = fold_decisions(tel, res.accept, valid, diag.fits,
                                  placed_arrival, stream_t.c0)
+        tenants = cs.tenants
+        if cfg.n_tenants:
+            with jax.named_scope("tenant_fold"):
+                tenants = fold_tenant_arrivals(tenants, stream_t.tenant,
+                                               stream_t.c0, placed_arrival)
         return CoreState(slots=slots, agg_el=agg_el, agg_vl=agg_vl,
-                         tel=tel), res.accept, diag
+                         tel=tel, tenants=tenants), res.accept, diag
 
     def decide_batch(policy: PolicyParams, cs: CoreState, util,
                      cand: MomentCurves, stream_t: ArrivalStream, valid):
@@ -636,7 +726,8 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
         arrivals — accepted-but-overflowed ones never became deployments,
         so they must not haunt the carried aggregate. Returns
         (cs, accept [A]). With telemetry the rider folds the batch's reason
-        counters and the admitted-arrival stream moments."""
+        counters and the admitted-arrival stream moments; with tenants the
+        placed arrivals' sizes fold into their tenants' rows."""
         cs, accept, _ = _decide_core(policy, cs, util, cand, stream_t, valid,
                                      verbose=False)
         return cs, accept
@@ -653,7 +744,9 @@ def make_admission_core(cfg: SimConfig, grid: jax.Array,
     core = AdmissionCore(cfg=cfg, grid=grid, policy_kind=policy_kind,
                          needs_moments=needs_moments, n_grid=n_grid,
                          init=init, refresh_aggregates=refresh_aggregates,
-                         apply_events=apply_events, candidates=candidates_fn,
+                         apply_events=apply_events,
+                         apply_observed=apply_observed, lookup=lookup,
+                         candidates=candidates_fn,
                          decide_batch=decide_batch,
                          decide_batch_traced=decide_batch_traced)
     if mesh is None:
@@ -705,6 +798,10 @@ def _shard_over_slots(core: AdmissionCore, mesh) -> AdmissionCore:
         raise ValueError(
             f"max_slots={cfg.max_slots} must be divisible by the "
             f"{n_shards}-device mesh")
+    if cfg.n_tenants:
+        raise ValueError(
+            "sharded admission core with n_tenants > 0: the tenant table "
+            "is not sharded; serve a tenant configuration unsharded")
     if cfg.agg_backend != AGG_FUSED:
         raise ValueError(
             f"sharded admission core requires agg_backend={AGG_FUSED!r} "

@@ -229,6 +229,7 @@ def make_run(cfg: SimConfig, horizon_grid: jax.Array, policy_kind: int,
 
         # 4. arrivals, admitted against the maintained aggregate -------------
         valid = jnp.arange(cfg.max_arrivals) < stream_t.n_arrivals
+        stream_t, _ = core.lookup(cs, stream_t)
         cand = core.candidates(stream_t)
         cs, accept = core.decide_batch(policy, cs, out.util, cand, stream_t,
                                        valid)

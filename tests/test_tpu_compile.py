@@ -114,6 +114,37 @@ def test_paper_full_core_steps_compile_for_one_v5e_chip(one_chip):
         assert mem.argument_size_in_bytes < 16 * 2**30
 
 
+def test_tenant_core_steps_compile_for_one_v5e_chip(one_chip):
+    """The paper cluster with the Azure trace's 5,958 tenants: the tick's
+    observed-events step with the tenant fold, and the decide with the
+    lookup and the arrival fold."""
+    cfg = PAPER_FULL._replace(n_tenants=5958)
+    core = make_admission_core(cfg, GRID, SECOND)
+    args = _core_args(core, one_chip)
+    assert args["cs"].tenants.shape == (2, 5958, 6)
+    ev = args["cs"].slots.cores
+    events = dict(core_deaths=ev, spont_death=jax.ShapeDtypeStruct(
+        ev.shape, jnp.bool_, sharding=one_chip), scaleout_cores=ev,
+        n_scaleouts=ev)
+
+    def observe(cs, events):
+        from types import SimpleNamespace
+
+        return core.apply_observed(cs, SimpleNamespace(**events))
+
+    def decide(policy, cs, util, stream_t, valid):
+        stream_t, informed = core.lookup(cs, stream_t)
+        return core.decide_batch(policy, cs, util, core.candidates(stream_t),
+                                 stream_t, valid), informed
+
+    for lowered in (
+            jax.jit(observe).lower(args["cs"], events),
+            jax.jit(decide).lower(args["policy"], args["cs"], args["util"],
+                                  args["stream"], args["valid"])):
+        mem = lowered.compile().memory_analysis()
+        assert mem.argument_size_in_bytes < 16 * 2**30
+
+
 def test_core_contractions_are_full_f32():
     """A TPU runs an f32 matmul at its default precision on bf16-rounded
     operands; every contraction of the admission steps must ask for
