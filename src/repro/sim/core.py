@@ -386,6 +386,13 @@ def _place_arrivals(state: SimState, accept, stream_t: ArrivalStream, cfg: SimCo
     [A, S] rank-match instead of A passes over the slot array. Accepted
     arrivals beyond the number of free slots are counted as slot overflow.
 
+    Each slot leaf takes its new values by a one-hot select over the arrival
+    axis of the match ``hit`` [A, S], with no gather indexed by slot: no two
+    arrivals hit one slot, so OR-folding the selected bit patterns over the
+    arrivals yields the one hit's value exactly (NaN, inf and -0.0 included).
+    Under the fleet's vmap a per-slot gather from the small arrival table
+    costs far more than this elementwise [A, S] pass.
+
     Returns (state, placed_arrival [A]) — the mask of accepted arrivals that
     actually landed in a slot, so the caller folds only *real* deployments
     into the maintained aggregate (overflowed arrivals must not haunt it).
@@ -402,10 +409,14 @@ def _place_arrivals(state: SimState, accept, stream_t: ArrivalStream, cfg: SimCo
 
     hit = free[None, :] & (rank[None, :] == ordinal[:, None]) & accept[:, None]
     placed = jnp.any(hit, axis=0)                      # [S]
-    src = jnp.argmax(hit, axis=0)                      # its arrival, if placed
 
     def merge(old, new_a):
-        return jnp.where(placed, new_a[src], old)
+        bits_t = jnp.dtype(f"uint{8 * new_a.dtype.itemsize}")
+        bits = jax.lax.bitcast_convert_type(new_a, bits_t)
+        picked = jnp.bitwise_or.reduce(
+            jnp.where(hit, bits[:, None], bits_t.type(0)), axis=0)
+        return jnp.where(placed,
+                         jax.lax.bitcast_convert_type(picked, new_a.dtype), old)
 
     cores = merge(state.cores, stream_t.c0)
     params = jax.tree.map(lambda o, n: merge(o, n), state.params,
